@@ -2,7 +2,7 @@
 
 Usage (inside a workflow step, after pytest wrote the report):
 
-    python .github/scripts/junit_summary.py --title "tier1 (jnp, 0.4.37)" \
+    python .github/scripts/junit_summary.py --title "tier1 (jnp)" \
         junit-*.xml
 
 Appends one pass/fail table (plus the names of any failed tests) to

@@ -7,10 +7,10 @@ Two cases feed ``BENCH_pr.json``:
   each device reduces its shard with segment_reduce, one O(k) ``psum``/
   ``pmax`` merges the mergeable summaries. Tracks shard_map + collective
   overhead even on a 1-device host.
-* **sharded-ingest scale curve** — the PR's headline: the full
-  data-parallel streaming path (``repro.sharded.ShardedIngestor``) run in
-  fresh subprocesses with 1/2/4 *forced host devices*
-  (``--xla_force_host_platform_device_count``), reporting rows/sec per
+* **sharded-ingest scale curve** — the full data-parallel streaming path
+  (``repro.sharded.ShardedIngestor``) over ``data_mesh(n)`` for n = 1/2/4
+  in one process (on the CPU, forced host devices via
+  ``--xla_force_host_platform_device_count``), reporting rows/sec per
   device count and the gated ``sharded_ingest_scaleup_x`` =
   rate(D_max)/rate(1). On a multi-core host this shows real weak scaling
   (target >= 1.5x at 4 devices); on the 1-core CI runner forced host
@@ -23,8 +23,6 @@ Run: PYTHONPATH=src python -m benchmarks.bench_distributed
 from __future__ import annotations
 
 import os
-import subprocess
-import sys
 import time
 
 import numpy as np
@@ -86,18 +84,16 @@ def tiny_config() -> dict:
 
 
 # --------------------------------------------------------------------------
-# Sharded-ingest weak-scaling curve (subprocess per device count)
+# Sharded-ingest weak-scaling curve (one process, one mesh per device count)
 # --------------------------------------------------------------------------
 
-def _shard_worker(n_rows: int, k: int, batch: int, seed: int) -> None:
-    """Child process: build a sharded synopsis over every (forced) device,
-    then time steady-state streaming ingest. Prints one parseable line."""
+def _ingest_rate(mesh, c, a, k: int, batch: int, seed: int) -> float:
+    """Build a sharded synopsis over ``mesh``, then time steady-state
+    streaming ingest. Returns rows/s."""
     from repro.sharded import build_synopsis_sharded
-    rng = np.random.default_rng(seed)
-    c = rng.normal(size=n_rows).astype(np.float32)
-    a = rng.lognormal(0, 1, n_rows).astype(np.float32)
-    ing, rep = build_synopsis_sharded(c, a, k=k, sample_budget=8 * k,
-                                      seed=seed, batch_rows=batch)
+    ing, _ = build_synopsis_sharded(c, a, k=k, sample_budget=8 * k,
+                                    seed=seed, batch_rows=batch, mesh=mesh)
+    rng = np.random.default_rng(seed + 1)
     cb = rng.normal(size=batch).astype(np.float32)
     ab = rng.lognormal(0, 1, batch).astype(np.float32)
     ing.ingest(cb, ab)                              # warmup / compile
@@ -107,40 +103,36 @@ def _shard_worker(n_rows: int, k: int, batch: int, seed: int) -> None:
     for _ in range(reps):
         ing.ingest(cb, ab)
     jax.block_until_ready(ing.state.delta_agg)
-    dt = time.perf_counter() - t0
-    print(f"SHARD_RATE devices={len(jax.devices())} "
-          f"ingest_rows_per_sec={reps * batch / dt:.1f} "
-          f"build_rows_per_sec={rep['rows_per_sec']:.1f}")
+    return reps * batch / (time.perf_counter() - t0)
 
 
 def run_scale(n_rows: int = 400_000, k: int = 64, batch: int = 65_536,
               device_counts: tuple = (1, 2, 4), seed: int = 0) -> dict:
-    """Parent: spawn one fresh interpreter per device count (XLA device
-    topology is fixed at backend init, so forcing host devices requires a
-    clean process) and assemble the scale curve."""
-    rates: dict[int, float] = {}
-    for nd in device_counts:
-        env = dict(os.environ)
-        env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count={nd} "
-                            + env.get("XLA_FLAGS", "")).strip()
-        cmd = [sys.executable, "-m", "benchmarks.bench_distributed",
-               "--shard-worker", str(n_rows), str(k), str(batch), str(seed)]
-        proc = subprocess.run(cmd, env=env, capture_output=True, text=True,
-                              timeout=900)
-        line = next((ln for ln in proc.stdout.splitlines()
-                     if ln.startswith("SHARD_RATE")), None)
-        if proc.returncode != 0 or line is None:
-            raise RuntimeError(
-                f"sharded scale worker (D={nd}) failed:\n"
-                f"{proc.stdout}\n{proc.stderr}")
-        rates[nd] = float(line.split("ingest_rows_per_sec=")[1].split()[0])
-    d_max = max(device_counts)
+    """The scale curve over ``data_mesh(n)`` for each of ``device_counts``,
+    all in this one process (a child process could not reach a chip this
+    process already holds). Forced host devices
+    (``--xla_force_host_platform_device_count``) give the CPU several; a
+    count the process cannot see is an error, not a shorter curve."""
+    from repro.sharded import data_mesh
+    counts = sorted(set(device_counts))
+    if counts[0] != 1 or counts[-1] > len(jax.devices()):
+        raise ValueError(
+            f"scale curve over device counts {counts} needs D=1 and "
+            f"{counts[-1]} visible devices, found {len(jax.devices())} "
+            "(set XLA_FLAGS=--xla_force_host_platform_device_count=N on "
+            "the CPU)")
+    rng = np.random.default_rng(seed)
+    c = rng.normal(size=n_rows).astype(np.float32)
+    a = rng.lognormal(0, 1, n_rows).astype(np.float32)
+    rates = {nd: _ingest_rate(data_mesh(nd), c, a, k, batch, seed)
+             for nd in counts}
+    d_max = max(counts)
     metrics = {"sharded_ingest_scaleup_x": rates[d_max] / rates[1]}
-    for nd in device_counts:
+    for nd in counts:
         metrics[f"sharded_ingest_mrows_per_s_d{nd}"] = rates[nd] / 1e6
     print(f"sharded ingest scale curve (n={n_rows:,} build rows, k={k}, "
           f"batch={batch:,}):")
-    for nd in device_counts:
+    for nd in counts:
         print(f"  D={nd}: {rates[nd] / 1e6:7.3f} M rows/s "
               f"({rates[nd] / rates[1]:.2f}x vs D=1)")
     print(f"  scale-up at D={d_max}: {metrics['sharded_ingest_scaleup_x']:.2f}x")
@@ -153,9 +145,9 @@ def tiny_scale_config() -> dict:
 
 
 if __name__ == "__main__":
-    if len(sys.argv) > 1 and sys.argv[1] == "--shard-worker":
-        _shard_worker(*(int(v) for v in sys.argv[2:6]))
-    elif os.environ.get("REPRO_BENCH_TINY"):
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
+    if os.environ.get("REPRO_BENCH_TINY"):
         run(**tiny_config())
         run_scale(**tiny_scale_config())
     else:

@@ -81,5 +81,7 @@ def run(backends=("jnp", "ref")):
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     bes = ("jnp", "ref", "pallas") if "--pallas" in sys.argv else ("jnp", "ref")
     run(bes)

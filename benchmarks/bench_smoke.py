@@ -52,7 +52,8 @@ def run() -> tuple[dict, list]:
     metrics.update(bench_partitions.run(**bench_partitions.tiny_config()))
     # multi-device serving path: psum merge of the mergeable summaries
     metrics.update(bench_distributed.run(**bench_distributed.tiny_config()))
-    # sharded-ingest weak scaling: fresh subprocess per forced device count
+    # sharded-ingest weak scaling over data_mesh(1/2/4), in this process
+    # (needs 4 visible devices: forced host devices on the CPU)
     metrics.update(bench_distributed.run_scale(
         **bench_distributed.tiny_scale_config()))
     # uncertainty smoke: empirical coverage + the build-path wall clock
@@ -80,4 +81,6 @@ def main(out_path: str = "BENCH_pr.json") -> None:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main(*sys.argv[1:2])

@@ -30,4 +30,6 @@ def run(B: int = 64):
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     run()

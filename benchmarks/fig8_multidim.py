@@ -35,4 +35,6 @@ def run(max_leaves: int = 64, rate: float = 0.02, max_dim: int = 4):
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     run()

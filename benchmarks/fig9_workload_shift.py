@@ -101,5 +101,7 @@ def run_streaming(max_leaves: int = 64, rate: float = 0.02,
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     run()
     run_streaming()

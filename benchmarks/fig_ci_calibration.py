@@ -134,4 +134,6 @@ def main(out_path: str | None = None) -> None:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main(*sys.argv[1:2])

@@ -179,5 +179,7 @@ def tiny_config() -> dict:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     import os
     run(**(tiny_config() if os.environ.get("REPRO_BENCH_TINY") else {}))

@@ -27,4 +27,6 @@ def run(rate: float = 0.005):
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     run()

@@ -101,4 +101,6 @@ def streaming_demo():
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -240,7 +240,6 @@ class PreparedQuery:
         self._syn = self._resolve_source()
         self._fn, self._statics, self._build = self._make_entry()
         self._aot = None
-        self._aot_failed = False
         self._calls = 0
 
     # Subclass hooks: which source view is pinned, which compiled entry
@@ -283,16 +282,12 @@ class PreparedQuery:
             same = False
         if not same:
             self._aot = None
-            self._aot_failed = False
 
     def _build_aot(self, args) -> None:
-        try:
-            self._aot = self._fn.lower(*args, **self._statics).compile()
-            self._engine._stats["aot_compiles"] += 1
-        except Exception:
-            # Keep serving through the jit path on any AOT quirk
-            # (jax-version drift, backend without lowering support, ...).
-            self._aot_failed = True
+        # A compile failure raises: serving must not silently leave the
+        # compiled path it was prepared for.
+        self._aot = self._fn.lower(*args, **self._statics).compile()
+        self._engine._stats["aot_compiles"] += 1
 
     def __call__(self, queries: QueryBatch,
                  plan_masks=None) -> dict[str, QueryResult]:
@@ -315,17 +310,20 @@ class PreparedQuery:
             self._engine._stats["fused_serves"] += 1
         args = self._build(self._syn, queries, plan_masks)
         self._calls += 1
-        if not _is_tracer(queries.lo):
-            if self._aot is None and not self._aot_failed and self._calls >= 2:
-                self._build_aot(args)
-            if self._aot is not None:
-                try:
-                    return self._aot(*args)
-                except TypeError:
-                    # e.g. same shape but different dtype than the lowering
-                    # was compiled for — the jit path recompiles and
-                    # answers; the handle loses only its fast path.
-                    pass
+        if _is_tracer(queries.lo):
+            # Traced inside a caller's jit: there is no concrete input to
+            # run an executable on, so the caller's program inlines ours.
+            self._engine._stats["aot_fallbacks"] += 1
+            return self._fn(*args, **self._statics)
+        if self._aot is None and self._calls >= 2:
+            self._build_aot(args)
+        if self._aot is not None:
+            try:
+                return self._aot(*args)
+            except TypeError:
+                # Same shape but a different dtype than the lowering was
+                # compiled for: the jit path recompiles and answers.
+                self._engine._stats["aot_fallbacks"] += 1
         return self._fn(*args, **self._statics)
 
 
@@ -396,6 +394,7 @@ class PassEngine:
         self._coalescer = None
         self._stats = {"hits": 0, "misses": 0, "evictions": 0,
                        "invalidations": 0, "aot_compiles": 0,
+                       "aot_fallbacks": 0,
                        "fused_serves": 0, "tier0_serves": 0,
                        "refine_steps": 0, "degraded_serves": 0}
         self._refine_ewma_ms = 0.0
@@ -540,7 +539,9 @@ class PassEngine:
 
     def stats(self) -> dict:
         """Plan-cache instrumentation: hits/misses/evictions/invalidations/
-        aot_compiles/fused_serves (calls answered through the fused
+        aot_compiles/aot_fallbacks (prepared calls served through jit
+        instead: a traced batch, or a dtype the executable was not
+        compiled for)/fused_serves (calls answered through the fused
         bootstrap megakernel path) plus current entry count and source
         epoch. When a :class:`repro.serve.RequestCoalescer` is attached to
         this engine, its snapshot (dispatch amortization, per-tenant

@@ -21,13 +21,17 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:                                   # jax >= 0.5 exposes it at top level
-    _shard_map = jax.shard_map
-except AttributeError:                 # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 from .types import Synopsis, QueryBatch
 from ..kernels import ops as kops
+
+
+def _auto_axes(mesh: Mesh) -> Mesh:
+    """The same devices and axis names with Auto axis types.
+
+    ``jax.make_mesh`` returns Explicit axes, under which the shard bodies'
+    closed-over synopsis arrays and jnp scatters would need sharding-in-
+    types annotations; everything here is laid out by the in/out specs."""
+    return Mesh(mesh.devices, mesh.axis_names)
 
 
 # --------------------------------------------------------------------------
@@ -56,7 +60,7 @@ def build_leaf_aggregates(mesh: Mesh, values: jnp.ndarray,
         return jnp.concatenate([sums, mins[:, None], maxs[:, None]], axis=1)
 
     row_spec = P(data_axes)
-    return _shard_map(shard_fn, mesh=mesh,
+    return jax.shard_map(shard_fn, mesh=_auto_axes(mesh),
                          in_specs=(row_spec, row_spec),
                          out_specs=P())(values, assign)
 
@@ -84,8 +88,8 @@ def serve_queries_sharded(mesh: Mesh, syn: Synopsis, queries: QueryBatch,
         return res.estimate, res.ci_half, res.lower, res.upper
 
     qspec = P(axes)
-    est, ci, lo, hi = _shard_map(
-        shard_fn, mesh=mesh, in_specs=(qspec, qspec),
+    est, ci, lo, hi = jax.shard_map(
+        shard_fn, mesh=_auto_axes(mesh), in_specs=(qspec, qspec),
         out_specs=(qspec,) * 4)(q_lo, q_hi)
     return est[:q], ci[:q], lo[:q], hi[:q]
 
@@ -136,8 +140,8 @@ def serve_samples_sharded(mesh: Mesh, syn: Synopsis, queries: QueryBatch,
     in_specs = (P(None, sample_axis, None), P(None, sample_axis),
                 P(None, sample_axis), P())
     # k_per_leaf refers to the GLOBAL stratum sample count.
-    return _shard_map(shard_fn, mesh=mesh, in_specs=in_specs,
-                         out_specs=(P(), P()))(
+    return jax.shard_map(shard_fn, mesh=_auto_axes(mesh),
+                         in_specs=in_specs, out_specs=(P(), P()))(
         syn.sample_c, syn.sample_a, syn.sample_valid, syn.k_per_leaf)
 
 

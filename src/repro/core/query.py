@@ -60,11 +60,24 @@ def answer(syn: Synopsis, queries: QueryBatch, kind: str = "sum",
 
 def ground_truth(c, a, queries: QueryBatch, kind: str = "sum",
                  chunk: int = 262144) -> np.ndarray:
+    return ground_truth_kinds(c, a, queries, (kind,), chunk=chunk)[kind]
+
+
+def ground_truth_kinds(c, a, queries: QueryBatch, kinds=("sum",),
+                       chunk: int = 262144) -> dict[str, np.ndarray]:
+    """Exact answers of several kinds from one chunked f64 host scan.
+
+    The predicate of every (query, row) pair is evaluated once; the
+    MIN/MAX passes run only when one of them is asked for."""
     c = np.asarray(c, dtype=np.float64)
     c2 = c[:, None] if c.ndim == 1 else c
     a = np.asarray(a, dtype=np.float64).reshape(-1)
     q_lo = np.asarray(queries.lo, dtype=np.float64)
     q_hi = np.asarray(queries.hi, dtype=np.float64)
+    for kind in kinds:
+        if kind not in ("sum", "count", "avg", "min", "max"):
+            raise ValueError(kind)
+    extremes = any(kind in ("min", "max") for kind in kinds)
     Q = q_lo.shape[0]
     s = np.zeros(Q)
     cnt = np.zeros(Q)
@@ -73,24 +86,18 @@ def ground_truth(c, a, queries: QueryBatch, kind: str = "sum",
     for start in range(0, c2.shape[0], chunk):
         cc = c2[start:start + chunk]
         aa = a[start:start + chunk]
-        pred = (np.all(q_lo[:, None, :] <= cc[None], axis=-1)
-                & np.all(cc[None] <= q_hi[:, None, :], axis=-1))
+        pred = np.ones((Q, cc.shape[0]), dtype=bool)
+        for j in range(cc.shape[1]):
+            cj = cc[:, j]
+            pred &= (q_lo[:, j:j + 1] <= cj) & (cj <= q_hi[:, j:j + 1])
         s += pred @ aa
         cnt += pred.sum(axis=1)
-        big = np.where(pred, aa[None], np.inf)
-        mn = np.minimum(mn, big.min(axis=1))
-        mx = np.maximum(mx, np.where(pred, aa[None], -np.inf).max(axis=1))
-    if kind == "sum":
-        return s
-    if kind == "count":
-        return cnt
-    if kind == "avg":
-        return s / np.maximum(cnt, 1)
-    if kind == "min":
-        return mn
-    if kind == "max":
-        return mx
-    raise ValueError(kind)
+        if extremes:
+            mn = np.minimum(mn, np.where(pred, aa[None], np.inf).min(axis=1))
+            mx = np.maximum(mx, np.where(pred, aa[None], -np.inf).max(axis=1))
+    out = {"sum": s, "count": cnt, "avg": s / np.maximum(cnt, 1),
+           "min": mn, "max": mx}
+    return {kind: out[kind] for kind in kinds}
 
 
 def ground_truth_join(c, a, keys, dim_keys, dim_attrs, queries: QueryBatch,
@@ -186,5 +193,6 @@ def ci_ratio(res: QueryResult, truth: np.ndarray) -> np.ndarray:
     return np.asarray(res.ci_half, dtype=np.float64) / np.maximum(np.abs(t), 1e-12)
 
 
-__all__ = ["answer", "ground_truth", "ground_truth_join", "random_queries",
-           "challenging_queries", "relative_error", "ci_ratio"]
+__all__ = ["answer", "ground_truth", "ground_truth_kinds", "ground_truth_join",
+           "random_queries", "challenging_queries", "relative_error",
+           "ci_ratio"]

@@ -31,7 +31,7 @@ from .registry import register_backend
 from .bootstrap import (bootstrap_moments as _boot_pallas, auto_block_r)
 from .route import (route_multid_dense as _route_dense,
                     route_multid_pallas as _route_pallas,
-                    auto_block_k)
+                    auto_block_k, ROW_TILE)
 from .segment_reduce import (segment_reduce as _segment_reduce_pallas,
                              weighted_segment_reduce as _wseg_pallas,
                              auto_block_n)
@@ -47,7 +47,9 @@ REL_NONE, REL_PARTIAL, REL_COVER = 0, 1, 2
 _BIG = jnp.float32(3.4e38)
 
 
-def _interpret() -> bool:
+def interpret_mode() -> bool:
+    """Whether the ``pallas`` backend runs its kernels in the Pallas
+    interpreter: everywhere but on a TPU, where they are compiled."""
     return jax.default_backend() != "tpu"
 
 
@@ -199,13 +201,13 @@ class KernelBackend:
         raise NotImplementedError
 
     # -- fused bootstrap replicate moments (DESIGN.md §10) -------------------
-    # One op for the whole (R, Q, k, 3) replicate-moment block; the default
+    # One op for the whole (R, 3, Q, k) replicate-moment block; the default
     # is the per-replicate oracle loop (structurally bit-identical to the
     # scan path), which `pallas`/`jnp` replace with genuinely fused
     # formulations. ``br=None`` auto-sizes the replicate block.
     def bootstrap_moments(self, sample_c, sample_a, sample_valid, weights,
                           q_lo, q_hi, **kw):
-        """``weights`` (R, k, s) resample weights -> (R, Q, k, 3) f32
+        """``weights`` (R, k, s) resample weights -> (R, 3, Q, k) f32
         [sum w*pred, sum w*pred*a, sum w*pred*a^2] per replicate."""
         k, s, d = sample_c.shape
         R = weights.shape[0]
@@ -221,9 +223,9 @@ class KernelBackend:
                                bk: int = 128, bs: int = 1024):
         # Oracle default: the scan path's per-replicate op, stacked.
         return jnp.stack([
-            self.weighted_moments_flat(sample_c, sample_a, sample_leaf,
-                                       weights[r], q_lo, q_hi, k,
-                                       bq=bq, bk=bk, bs=bs)
+            jnp.moveaxis(self.weighted_moments_flat(
+                sample_c, sample_a, sample_leaf, weights[r], q_lo, q_hi, k,
+                bq=bq, bk=bk, bs=bs), -1, 0)
             for r in range(weights.shape[0])])
 
     # -- multi-D batch routing (streaming ingest hot path) -------------------
@@ -302,7 +304,7 @@ class PallasBackend(KernelBackend):
         lo_t, hi_t, agg, qlo_t, qhi_t = _pad_query_eval_inputs(
             leaf_lo, leaf_hi, leaf_agg, q_lo, q_hi, bq, bk)
         rel, exact = _query_eval_pallas(lo_t, hi_t, agg, qlo_t, qhi_t, d,
-                                        bq=bq, bk=bk, interpret=_interpret())
+                                        bq=bq, bk=bk, interpret=interpret_mode())
         return rel[:Q, :k], exact[:Q, :A]
 
     def stratified_moments_flat(self, sample_c, sample_a, sample_leaf,
@@ -314,8 +316,8 @@ class PallasBackend(KernelBackend):
             sample_c, sample_a, sample_leaf, q_lo, q_hi, bq, bs)
         k_pad = k + ((-k) % bk)
         out = _strat_pallas(c_t, a, leaf, qlo_t, qhi_t, k_pad, d,
-                            bq=bq, bk=bk, bs=bs, interpret=_interpret())
-        return out[:Q, :k]
+                            bq=bq, bk=bk, bs=bs, interpret=interpret_mode())
+        return jnp.moveaxis(out[:, :Q, :k], 0, -1)
 
     def weighted_moments_flat(self, sample_c, sample_a, sample_leaf, weights,
                               q_lo, q_hi, k: int, bq: int = 128,
@@ -327,8 +329,8 @@ class PallasBackend(KernelBackend):
         w = _pad_axis(weights.astype(jnp.float32), bs, 0)
         k_pad = k + ((-k) % bk)
         out = _wstrat_pallas(c_t, a, leaf, w, qlo_t, qhi_t, k_pad, d,
-                             bq=bq, bk=bk, bs=bs, interpret=_interpret())
-        return out[:Q, :k]
+                             bq=bq, bk=bk, bs=bs, interpret=interpret_mode())
+        return jnp.moveaxis(out[:, :Q, :k], 0, -1)
 
     def bootstrap_moments_flat(self, sample_c, sample_a, sample_leaf,
                                weights, q_lo, q_hi, k: int,
@@ -344,23 +346,23 @@ class PallasBackend(KernelBackend):
         k_pad = k + ((-k) % bk)
         out = _boot_pallas(c_t, a, leaf, w, qlo_t, qhi_t, k_pad, d,
                            br=br, bq=bq, bk=bk, bs=bs,
-                           interpret=_interpret())
-        return out[:R, :Q, :k]
+                           interpret=interpret_mode())
+        return out[:R, :, :Q, :k]
 
     def route_multid(self, leaf_lo, leaf_hi, c, bk: int | None = None):
         b, d = c.shape
         k = leaf_lo.shape[0]
         bk = bk or auto_block_k(k)
-        bb = 256 if b >= 256 else 8 * ((b + 7) // 8)
+        bb = min(ROW_TILE, 128 * ((b + 127) // 128))
         # Padding strata are inverted ±BIG boxes: unreachable distance.
-        lo_t = _pad_axis(_transpose_coords(leaf_lo.astype(jnp.float32)),
-                         bk, 1, fill=_ref.POS_BIG)
-        hi_t = _pad_axis(_transpose_coords(leaf_hi.astype(jnp.float32)),
-                         bk, 1, fill=_ref.NEG_BIG)
+        lo = _pad_axis(_pad_axis(leaf_lo.astype(jnp.float32), D_PAD, 1),
+                       bk, 0, fill=_ref.POS_BIG)
+        hi = _pad_axis(_pad_axis(leaf_hi.astype(jnp.float32), D_PAD, 1),
+                       bk, 0, fill=_ref.NEG_BIG)
         c_t = _pad_axis(_transpose_coords(c.astype(jnp.float32)), bb, 1)
-        idx, dist = _route_pallas(lo_t, hi_t, c_t, d, bb=bb, bk=bk,
-                                  interpret=_interpret())
-        return idx[:b], dist[:b]
+        idx, dist = _route_pallas(lo, hi, c_t, d, bb=bb, bk=bk,
+                                  interpret=interpret_mode())
+        return idx[0, :b], dist[0, :b]
 
     def segment_reduce(self, values, seg_ids, k: int, bn: int | None = 2048,
                        bk: int = 256):
@@ -369,7 +371,7 @@ class PallasBackend(KernelBackend):
         ids = _pad_axis(seg_ids.astype(jnp.int32), bn, 0, fill=-1)
         k_pad = k + ((-k) % bk)
         out = _segment_reduce_pallas(v, ids, k_pad, bn=bn, bk=bk,
-                                     interpret=_interpret())
+                                     interpret=interpret_mode())
         return out[:k, :5]
 
     def weighted_segment_reduce(self, values, weights, seg_ids, k: int,
@@ -380,7 +382,7 @@ class PallasBackend(KernelBackend):
         ids = _pad_axis(seg_ids.astype(jnp.int32), bn, 0, fill=-1)
         k_pad = k + ((-k) % bk)
         out = _wseg_pallas(v, w, ids, k_pad, bn=bn, bk=bk,
-                           interpret=_interpret())
+                           interpret=interpret_mode())
         return out[:k, :3]
 
 
@@ -467,10 +469,10 @@ class JnpBackend(KernelBackend):
             p = pred[None] * wt[:, None]                          # (br,Q,k,s)
             return carry, jnp.stack(
                 [tree_sum_last(p), tree_sum_last(p * a),
-                 tree_sum_last(p * a * a)], axis=-1)
+                 tree_sum_last(p * a * a)], axis=1)               # (br,3,Q,k)
 
         _, out = jax.lax.scan(step, 0, w.reshape(-1, br, k, s))
-        return out.reshape(-1, Q, k, 3)[:R]
+        return out.reshape(-1, 3, Q, k)[:R]
 
     def weighted_segment_reduce(self, values, weights, seg_ids, k: int,
                                 bn: int | None = 2048, bk: int = 256):
@@ -534,4 +536,4 @@ class JnpBackend(KernelBackend):
 
 __all__ = ["KernelBackend", "PallasBackend", "RefBackend", "JnpBackend",
            "classify_leaves", "sample_moments", "weighted_sample_moments",
-           "D_PAD"]
+           "interpret_mode", "D_PAD"]

@@ -6,7 +6,7 @@ every replicate r, the weighted relevant-sample moments the
 vector. The scan path dispatches that kernel once per replicate — R full
 passes over the sample arrays. This megakernel instead revisits each
 sample tile once per (replicate-tile, query-tile, stratum-tile) and emits
-the whole (R, Q, k, 3) replicate-moment block from a single
+the whole (R, 3, Q, k) replicate-moment block from a single
 ``pallas_call``: the sample tile (coordinates, values, leaf ids) is loaded
 into VMEM once per grid step and reused for all BR replicates of the
 weight tile, so the data pass is amortized over the replicate block
@@ -16,7 +16,7 @@ Bit-identity contract (DESIGN.md §10): the per-replicate arithmetic is an
 *unrolled loop of exactly the 2-D matmuls the scan path's weighted kernel
 performs* — same (BQ, BS) x (BS, BK) contraction shapes, same sample-tile
 accumulation order (the s grid dimension stays innermost/sequential), so a
-replicate's (Q, k, 3) slice is bit-identical to one
+replicate's (3, Q, k) slice is bit-identical to one
 ``stratified_weighted_moments`` call with the same weight row. Resample
 weights are NOT generated in-kernel: they arrive as an (R, S) operand
 drawn in one batched ``fold_in(key, r)`` threefry pass (see
@@ -26,7 +26,9 @@ sequential scan path on every jax version; the kernel streams them in
 step.
 
 Grid: (r_tiles, q_tiles, k_tiles, s_tiles) with the sample dimension
-innermost (sequential accumulation into the (BR, BQ, BK, 3) output tile).
+innermost (sequential accumulation into the (BR, 3, BQ, BK) output tile).
+As in ``stratified_estimate``, the moment axis sits ahead of the (BQ, BK)
+tile so that the stratum tile stays on the lanes.
 """
 from __future__ import annotations
 
@@ -35,6 +37,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from .stratified_estimate import BF16, weighted_moment_tile
 
 
 # Replicate-tile size: BR unrolled per-replicate matmul groups per grid
@@ -69,20 +73,11 @@ def _kernel(c_ref, a_ref, leaf_ref, w_ref, qlo_ref, qhi_ref, out_ref,
     predb = pred.astype(jnp.float32)
     k_base = kt * bk
     k_iota = jax.lax.broadcasted_iota(jnp.int32, (bs, bk), 1) + k_base
-    onehot = (leaf[:, None] == k_iota).astype(jnp.float32)  # (BS, BK)
-
-    def mm(lhs):   # (BQ, BS) @ (BS, BK) — the scan kernel's exact shape
-        return jax.lax.dot_general(lhs, onehot, (((1,), (0,)), ((), ())),
-                                   preferred_element_type=jnp.float32)
-
-    tiles = []
-    for r in range(br):                   # unrolled replicate loop
-        predf = predb * w_ref[r, :][None, :]
-        kp = mm(predf)
-        sm = mm(predf * a[None, :])
-        sq = mm(predf * (a * a)[None, :])
-        tiles.append(jnp.stack([kp, sm, sq], axis=-1))    # (BQ, BK, 3)
-    tile = jnp.stack(tiles, axis=0)                       # (BR, BQ, BK, 3)
+    onehot = (leaf[:, None] == k_iota).astype(jnp.float32).astype(BF16)
+    # each replicate: exactly the weighted kernel's tile arithmetic
+    tiles = [weighted_moment_tile(predb, a, onehot, w_ref[r, :])
+             for r in range(br)]                          # unrolled
+    tile = jnp.stack(tiles, axis=0)                       # (BR, 3, BQ, BK)
 
     @pl.when(st == 0)
     def _init():
@@ -102,9 +97,10 @@ def bootstrap_moments(c_t: jnp.ndarray, a: jnp.ndarray, leaf: jnp.ndarray,
                       bk: int = 128, bs: int = 1024,
                       interpret: bool = True) -> jnp.ndarray:
     """c_t (d_pad, S) f32; a (S,) f32; leaf (S,) int32 (-1 padding);
-    w (R, S) f32 resample weights (padding samples carry w == 0);
+    w (R, S) f32 resample weights, small integers at most 256 (padding
+    samples carry w == 0);
     qlo_t/qhi_t (d_pad, Q). R % br == 0, S % bs == 0, Q % bq == 0,
-    k % bk == 0. Returns (R, Q, k, 3) f32 =
+    k % bk == 0. Returns (R, 3, Q, k) f32 =
     [sum w*pred, sum w*pred*a, sum w*pred*a^2] per replicate."""
     d_pad, S = c_t.shape
     R = w.shape[0]
@@ -123,9 +119,9 @@ def bootstrap_moments(c_t: jnp.ndarray, a: jnp.ndarray, leaf: jnp.ndarray,
             pl.BlockSpec((d_pad, bq), lambda rt, qt, kt, st: (0, qt)),
             pl.BlockSpec((d_pad, bq), lambda rt, qt, kt, st: (0, qt)),
         ],
-        out_specs=pl.BlockSpec((br, bq, bk, 3),
-                               lambda rt, qt, kt, st: (rt, qt, kt, 0)),
-        out_shape=jax.ShapeDtypeStruct((R, Q, k, 3), jnp.float32),
+        out_specs=pl.BlockSpec((br, 3, bq, bk),
+                               lambda rt, qt, kt, st: (rt, 0, qt, kt)),
+        out_shape=jax.ShapeDtypeStruct((R, 3, Q, k), jnp.float32),
         interpret=interpret,
     )(c_t, a, leaf, w, qlo_t, qhi_t)
 

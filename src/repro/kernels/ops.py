@@ -76,7 +76,7 @@ def bootstrap_moments_op(sample_c: jnp.ndarray, sample_a: jnp.ndarray,
     weighted relevant-sample moments in one op. sample_c (k, s, d),
     sample_a/sample_valid (k, s), weights (R, k, s) resample weights;
     q_lo/q_hi (Q, d). ``br=None`` auto-sizes the replicate block.
-    Returns (R, Q, k, 3) = [sum w*pred, sum w*pred*a, sum w*pred*a^2]."""
+    Returns (R, 3, Q, k) = [sum w*pred, sum w*pred*a, sum w*pred*a^2]."""
     return get_backend(backend).bootstrap_moments(
         sample_c, sample_a, sample_valid, weights, q_lo, q_hi, br=br)
 

@@ -37,8 +37,11 @@ def _kernel(lo_ref, hi_ref, agg_ref, qlo_ref, qhi_ref, rel_ref, exact_ref,
     cover = cover & nonempty[None, :]
     rel_ref[...] = jnp.where(cover, 2, jnp.where(disjoint, 0, 1)
                              ).astype(jnp.int32)
+    # HIGHEST: at the default precision the MXU rounds the f32 leaf
+    # aggregates to bf16, and covered answers stop being exact.
     part = jax.lax.dot_general(cover.astype(jnp.float32), agg_ref[...],
                                (((1,), (0,)), ((), ())),
+                               precision=jax.lax.Precision.HIGHEST,
                                preferred_element_type=jnp.float32)
 
     @pl.when(kt == 0)

@@ -30,8 +30,10 @@ from jax.experimental import pallas as pl
 
 from .ref import NEG_BIG, POS_BIG
 
-# Leaf-box tile of the streamed dimension (lane-aligned).
+# Leaf-box tile of the streamed dimension (sublane-aligned).
 BOX_TILE = 128
+# Row tile of the Pallas router (lane-aligned).
+ROW_TILE = 1024
 
 
 def auto_block_k(k: int, tile: int = BOX_TILE) -> int:
@@ -111,16 +113,19 @@ def route_multid_tiled(leaf_lo, leaf_hi, c, bk: int | None = None):
 
 def _route_kernel(lo_ref, hi_ref, c_ref, dist_ref, idx_ref, *, bk: int,
                   d: int):
+    # Leaves on sublanes, rows on lanes: the per-row (min, argmin) pair
+    # reduces over sublanes and lands as a lane-dense (1, BB) row.
     kt = pl.program_id(1)
     dist = None
     for j in range(d):
-        lo_j = lo_ref[j, :][None, :]                       # (1, BK)
-        hi_j = hi_ref[j, :][None, :]
-        cj = c_ref[j, :][:, None]                          # (BB, 1)
+        lo_j = lo_ref[:, j:j + 1]                          # (BK, 1)
+        hi_j = hi_ref[:, j:j + 1]
+        cj = c_ref[j:j + 1, :]                             # (1, BB)
         dj = jnp.maximum(jnp.maximum(lo_j - cj, cj - hi_j), 0.0)
-        dist = dj if dist is None else dist + dj           # (BB, BK)
-    loc = jnp.min(dist, axis=1)
-    arg = jnp.argmin(dist, axis=1).astype(jnp.int32) + kt * bk
+        dist = dj if dist is None else dist + dj           # (BK, BB)
+    loc = jnp.min(dist, axis=0, keepdims=True)             # (1, BB)
+    arg = (jnp.argmin(dist, axis=0, keepdims=True).astype(jnp.int32)
+           + kt * bk)
 
     @pl.when(kt == 0)
     def _init():
@@ -135,17 +140,18 @@ def _route_kernel(lo_ref, hi_ref, c_ref, dist_ref, idx_ref, *, bk: int,
 
 
 @functools.partial(jax.jit, static_argnames=("d", "bb", "bk", "interpret"))
-def route_multid_pallas(lo_t: jnp.ndarray, hi_t: jnp.ndarray,
-                        c_t: jnp.ndarray, d: int, bb: int = 256,
+def route_multid_pallas(lo: jnp.ndarray, hi: jnp.ndarray,
+                        c_t: jnp.ndarray, d: int, bb: int = ROW_TILE,
                         bk: int = BOX_TILE, interpret: bool = True
                         ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """lo_t/hi_t (d_pad, k_pad) transposed leaf boxes (padding strata at
-    ±BIG inverted); c_t (d_pad, B_pad) transposed row coordinates.
-    B_pad % bb == 0, k_pad % bk == 0. Returns (idx (B_pad,) int32,
-    dist (B_pad,) f32) — the grid keeps the (min, argmin) running pair in
-    the VMEM output block across the leaf-tile dimension, so no (B, k)
-    buffer ever exists."""
-    d_pad, k_pad = lo_t.shape
+    """lo/hi (k_pad, d_pad) leaf boxes (padding strata at ±BIG inverted);
+    c_t (d_pad, B_pad) transposed row coordinates. B_pad % bb == 0,
+    k_pad % bk == 0. Returns (idx (1, B_pad) int32, dist (1, B_pad) f32)
+    — the grid keeps the (min, argmin) running pair in the VMEM output
+    block across the leaf-tile dimension, so no (B, k) buffer ever
+    exists. The outputs are 2-D rows: a 1-D (bb,) block disagrees with
+    the tiling XLA gives a long 1-D array on TPU."""
+    k_pad, d_pad = lo.shape
     B = c_t.shape[1]
     assert B % bb == 0 and k_pad % bk == 0, (B, bb, k_pad, bk)
     grid = (B // bb, k_pad // bk)
@@ -153,20 +159,20 @@ def route_multid_pallas(lo_t: jnp.ndarray, hi_t: jnp.ndarray,
         functools.partial(_route_kernel, bk=bk, d=d),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((d_pad, bk), lambda bt, kt: (0, kt)),
-            pl.BlockSpec((d_pad, bk), lambda bt, kt: (0, kt)),
+            pl.BlockSpec((bk, d_pad), lambda bt, kt: (kt, 0)),
+            pl.BlockSpec((bk, d_pad), lambda bt, kt: (kt, 0)),
             pl.BlockSpec((d_pad, bb), lambda bt, kt: (0, bt)),
         ],
         out_specs=[
-            pl.BlockSpec((bb,), lambda bt, kt: (bt,)),
-            pl.BlockSpec((bb,), lambda bt, kt: (bt,)),
+            pl.BlockSpec((1, bb), lambda bt, kt: (0, bt)),
+            pl.BlockSpec((1, bb), lambda bt, kt: (0, bt)),
         ],
-        out_shape=[jax.ShapeDtypeStruct((B,), jnp.float32),
-                   jax.ShapeDtypeStruct((B,), jnp.int32)],
+        out_shape=[jax.ShapeDtypeStruct((1, B), jnp.float32),
+                   jax.ShapeDtypeStruct((1, B), jnp.int32)],
         interpret=interpret,
-    )(lo_t, hi_t, c_t)
+    )(lo, hi, c_t)
     return idx, dist
 
 
-__all__ = ["dist_matrix", "route_multid_dense", "route_multid_tiled", "route_multid_pallas",
-           "auto_block_k", "BOX_TILE"]
+__all__ = ["dist_matrix", "route_multid_dense", "route_multid_tiled",
+           "route_multid_pallas", "auto_block_k", "BOX_TILE", "ROW_TILE"]

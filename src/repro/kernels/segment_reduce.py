@@ -51,8 +51,11 @@ def _kernel(v_ref, id_ref, out_ref, *, bk: int):
     k_iota = jax.lax.broadcasted_iota(jnp.int32, (v.shape[0], bk), 1) + k_base
     onehot = (ids[:, None] == k_iota).astype(jnp.float32)       # (BN, BK)
     moments = jnp.stack([v, v * v, jnp.ones_like(v)], axis=-1)  # (BN, 3)
+    # HIGHEST: the default MXU precision rounds the f32 values to bf16,
+    # and the per-leaf sums stop being the exact aggregates.
     part = jax.lax.dot_general(onehot, moments,
                                (((0,), (0,)), ((), ())),
+                               precision=jax.lax.Precision.HIGHEST,
                                preferred_element_type=jnp.float32)  # (BK,3)
     sel = onehot > 0
     vmin = jnp.min(jnp.where(sel, v[:, None], POS_BIG), axis=0)     # (BK,)
@@ -112,6 +115,7 @@ def _kernel_weighted(v_ref, w_ref, id_ref, out_ref, *, bk: int):
     moments = jnp.stack([w * v, w * v * v, w], axis=-1)         # (BN, 3)
     part = jax.lax.dot_general(onehot, moments,
                                (((0,), (0,)), ((), ())),
+                               precision=jax.lax.Precision.HIGHEST,
                                preferred_element_type=jnp.float32)  # (BK,3)
 
     @pl.when(j == 0)

@@ -43,11 +43,9 @@ def _catalog_shard_merge(c_blk, a_blk, pid_blk, mask, bin_lo, bin_hi,
             m_agg=m_agg)
 
     spec = P(SHARD_AXIS)
-    # check_rep=False for the same reason as the state merge: every output
-    # is a full-axis reduction, genuinely replicated.
     return shard_map(shard_fn, mesh=mesh,
                      in_specs=(spec, spec, spec, spec, P(), P()),
-                     out_specs=P(), check_rep=False)(
+                     out_specs=P())(
         c_blk, a_blk, pid_blk, mask, bin_lo, bin_hi)
 
 

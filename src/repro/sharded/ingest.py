@@ -116,12 +116,14 @@ def _sharded_ingest_step(state: StreamState, c: jnp.ndarray, a: jnp.ndarray,
         return jax.tree_util.tree_map(lambda x: x[None], new)
 
     spec = P(SHARD_AXIS)
-    # check_rep=False: the replication checker has no rule for pallas_call,
-    # so the pallas backend's kernels would abort tracing; nothing here is
-    # claimed replicated anyway (all out_specs are sharded).
+    # check_vma=False: Pallas kernel bodies carry no varying-manual-axes
+    # types, so an in-kernel constant (the one-hot's broadcasted_iota)
+    # stays unvarying and the checker refuses to compare it with the
+    # varying block operands. Nothing here is claimed replicated anyway
+    # (all out_specs are sharded).
     return shard_map(shard_fn, mesh=mesh,
                      in_specs=(spec, spec, spec, spec, spec, P(), P()),
-                     out_specs=spec, check_rep=False)(state, c, a, keys, mask,
+                     out_specs=spec, check_vma=False)(state, c, a, keys, mask,
                                                       qlo, qhi)
 
 
@@ -160,11 +162,12 @@ def _sharded_build_step(state: StreamState, c: jnp.ndarray, a: jnp.ndarray,
         return jax.tree_util.tree_map(lambda x: x[None], new)
 
     spec = P(SHARD_AXIS)
-    # check_rep=False: same pallas_call caveat as _sharded_ingest_step.
+    # check_vma=False: same Pallas kernel-body caveat as
+    # _sharded_ingest_step.
     return shard_map(shard_fn, mesh=mesh,
                      in_specs=(spec, spec, spec, spec, spec, P(), P(),
                                P(), P()),
-                     out_specs=spec, check_rep=False)(state, c, a, keys, mask,
+                     out_specs=spec, check_vma=False)(state, c, a, keys, mask,
                                                       route_lo, route_hi,
                                                       qlo, qhi)
 

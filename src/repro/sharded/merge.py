@@ -5,7 +5,8 @@ is one ``psum`` of the (k, 3) additive columns, one ``pmin``/``pmax`` pair
 for extremes and boxes, and a tiled ``all_gather`` that reassembles the
 per-shard reservoir slices into the (k, S) serving arrays — a few
 kilobytes total, independent of the row count. The gathered global
-:class:`StreamState` then flows through the *single-device* delta-merge
+:class:`StreamState` then moves to the mesh's first device and flows
+through the *single-device* delta-merge
 (:func:`repro.streaming.delta.merge_synopsis`), so the serving epilogue —
 tree lift, fixed-structure contractions, prepared AOT executables — is
 byte-for-byte the same program regardless of the shard count.
@@ -49,11 +50,12 @@ def _gather_state(state: StreamState, mesh: Mesh) -> StreamState:
             oob=jax.lax.psum(oob[0], ax))
 
     spec = P(SHARD_AXIS)
-    # check_rep=False: the 0.4.x replication checker cannot see through
-    # all_gather (psum outputs it infers fine); every output here is
-    # genuinely replicated — gathers and full-axis reductions only.
+    # check_vma=False: lax.all_gather types its result as varying over the
+    # gathered axis (the invariant-typed gather is not public API), so the
+    # checker cannot infer the sample slices replicated; every output here
+    # is genuinely replicated — tiled gathers and full-axis reductions only.
     return shard_map(shard_fn, mesh=mesh, in_specs=(spec,) * 9,
-                     out_specs=P(), check_rep=False)(
+                     out_specs=P(), check_vma=False)(
         state.leaf_lo, state.leaf_hi, state.delta_agg, state.sample_c,
         state.sample_a, state.sample_valid, state.k_per_leaf, state.seen,
         state.oob)
@@ -61,9 +63,18 @@ def _gather_state(state: StreamState, mesh: Mesh) -> StreamState:
 
 def merge_sharded(base: Synopsis, state: StreamState, subtree: jnp.ndarray,
                   *, total_rows, mesh: Mesh) -> Synopsis:
-    """Serving synopsis = base ⊕ (collectively merged sharded delta)."""
-    return merge_synopsis(base, _gather_state(state, mesh), subtree,
-                          total_rows=total_rows)
+    """Serving synopsis = base ⊕ (collectively merged sharded delta), on
+    the mesh's first device.
+
+    The gathered state is replicated over the mesh; serving it there would
+    make every serving program span all of the mesh's devices, and a
+    Pallas (Mosaic) kernel cannot be partitioned automatically, even over
+    replicated operands. One device holds the merged synopsis (O(k + S)
+    values) and serves it; the state stays sharded."""
+    dev = mesh.devices.flat[0]
+    base, gathered, subtree = jax.device_put(
+        (base, _gather_state(state, mesh), subtree), dev)
+    return merge_synopsis(base, gathered, subtree, total_rows=total_rows)
 
 
 __all__ = ["merge_sharded"]

@@ -14,10 +14,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:                                   # jax >= 0.5 exposes it at top level
-    shard_map = jax.shard_map
-except AttributeError:                 # jax 0.4.x
-    from jax.experimental.shard_map import shard_map
+shard_map = jax.shard_map
 
 SHARD_AXIS = "shards"
 
@@ -47,10 +44,15 @@ def split_rows(c: jnp.ndarray, a: jnp.ndarray, n_shards: int
                ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """(B, d) rows -> per-shard (D, Bs, d) blocks + (D, Bs) validity mask.
 
-    Rows are dealt out in contiguous blocks; a ragged tail is padded with
-    the last real row (masked out downstream, so the values never matter —
-    repeating a real row keeps every padded coordinate inside the data's
-    support, which keeps routing shapes trivially valid).
+    Rows are dealt out round-robin (row r to shard r mod D). Each shard
+    keeps its own reservoir slice of every stratum, and the merged sample
+    is uniform only if every shard sees the same share of each stratum's
+    rows; batches that arrive ordered by a predicate column (time-ordered
+    appends) would give contiguous blocks very unequal shares. A ragged
+    tail is padded with the last real row (masked out downstream, so the
+    values never matter — repeating a real row keeps every padded
+    coordinate inside the data's support, which keeps routing shapes
+    trivially valid).
     """
     b = a.shape[0]
     bs = -(-b // n_shards)                     # ceil
@@ -58,8 +60,9 @@ def split_rows(c: jnp.ndarray, a: jnp.ndarray, n_shards: int
     if pad:
         c = jnp.concatenate([c, jnp.repeat(c[-1:], pad, axis=0)], axis=0)
         a = jnp.concatenate([a, jnp.repeat(a[-1:], pad)], axis=0)
-    mask = (jnp.arange(n_shards * bs) < b).reshape(n_shards, bs)
-    return (c.reshape(n_shards, bs, -1), a.reshape(n_shards, bs), mask)
+    mask = (jnp.arange(n_shards * bs) < b).reshape(bs, n_shards).T
+    return (jnp.swapaxes(c.reshape(bs, n_shards, -1), 0, 1),
+            a.reshape(bs, n_shards).T, mask)
 
 
 __all__ = ["Mesh", "P", "shard_map", "SHARD_AXIS", "data_mesh",

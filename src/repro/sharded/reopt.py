@@ -2,11 +2,11 @@
 
 Same loop as :mod:`repro.streaming.policy`, scaled out: the drift signals
 (``staleness``/``oob_frac``) accumulate shard-locally inside the sharded
-ingestor; when a :class:`DriftPolicy` trips, the DP runs over the
-*collectively merged* reservoir pool (its per-shard partial moments were
-composed by the O(k) merge — no raw rows move), the fresh cuts broadcast
-to every shard as a static skeleton, and the rebuild streams the caller's
-rows through the data-parallel fill. The expensive O(N) phase is the
+ingestor; when a :class:`DriftPolicy` trips, the DP runs over (at most
+``OPT_SAMPLES`` of) the *collectively merged* reservoir pool (its
+per-shard partial moments were composed by the O(k) merge — no raw rows
+move), the fresh cuts broadcast to every shard as a static skeleton, and
+the rebuild streams the caller's rows through the data-parallel fill. The expensive O(N) phase is the
 fill, and it is the part that scales with the mesh.
 """
 from __future__ import annotations
@@ -14,33 +14,23 @@ from __future__ import annotations
 import numpy as np
 import jax.numpy as jnp
 
-from ..core import dp as dp_mod
-from ..streaming.policy import DriftPolicy
+from ..streaming.policy import DriftPolicy, pool_thresholds
 from .build import fill_skeleton, thresholds_to_boxes
 from .ingest import ShardedIngestor
 
 
 def reoptimize_cuts_sharded(ing: ShardedIngestor, k: int | None = None
                             ) -> tuple[jnp.ndarray, float]:
-    """DP cuts over the merged (all-shard) reservoir pool. 1-D only —
-    KD synopses rebuild through ``build_synopsis_sharded``. Inherits the
+    """DP cuts over the merged (all-shard) reservoir pool, at most
+    ``OPT_SAMPLES`` of it (``streaming.policy.pool_thresholds``). 1-D only
+    — KD synopses rebuild through ``build_synopsis_sharded``. Inherits the
     equal-capacity-pool caveat of ``streaming.policy.reoptimize_cuts``."""
     merged = ing.as_synopsis()
     if merged.d != 1:
         raise ValueError("sharded re-optimization supports 1-D synopses; "
                          "rebuild KD synopses with build_synopsis_sharded")
-    k = k or merged.num_leaves
-    valid = merged.sample_valid.reshape(-1)
-    m = int(jnp.sum(valid))
-    if m < k + 1:
-        raise ValueError(
-            f"merged reservoir pool too small to re-optimize: {m} < {k + 1}")
-    cs = merged.sample_c.reshape(-1)
-    as_ = merged.sample_a.reshape(-1)
-    order = jnp.argsort(jnp.where(valid, cs, jnp.inf))[:m]
-    cuts, vmax = dp_mod.dp_monotone_jnp(as_[order], k)
-    thr = dp_mod.cuts_to_thresholds_jnp(cs[order], cuts)
-    return thr, float(vmax)
+    return pool_thresholds(merged.sample_c, merged.sample_a,
+                           merged.sample_valid, k or merged.num_leaves)
 
 
 def reoptimize_sharded(ing: ShardedIngestor, c, a, *, k: int | None = None,

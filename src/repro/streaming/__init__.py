@@ -12,8 +12,8 @@ vectorized batched inserts and delta-merge serving (DESIGN.md §6):
   serving-ready :class:`~repro.core.types.Synopsis` without re-uploading
   O(K) state per batch.
 * :mod:`policy`  — drift signals (``staleness``, out-of-box fraction) and
-  the on-device re-optimization loop: ``dp_monotone_jnp`` over the live
-  reservoir pool -> fresh cuts -> rebuild + sample re-stratification.
+  the on-device re-optimization loop: ``dp_monotone_jnp`` over (at most
+  ``OPT_SAMPLES`` of) the live reservoir pool -> fresh cuts -> rebuild + sample re-stratification.
 * :mod:`join_ingest` — ``JoinStreamingIngestor``: the base transition plus
   streamed (stratum x dim-partition) cell aggregates and keyed universe-
   sample appends for fk-join serving (DESIGN.md §13).

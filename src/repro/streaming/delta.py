@@ -56,7 +56,10 @@ def _merge_arrays(base: Synopsis, state, subtree: jnp.ndarray):
 
     # lift the leaf delta onto every tree node through the subtree mask
     subf = subtree.astype(jnp.float32)                         # (V, k)
-    d_sums = subf @ delta[:, 0:3]                              # (V, 3)
+    # HIGHEST: at the default precision a TPU matmul rounds the f32 sums
+    # to bf16, and the lifted node aggregates stop being exact.
+    d_sums = jnp.matmul(subf, delta[:, 0:3],
+                        precision=jax.lax.Precision.HIGHEST)   # (V, 3)
     d_min = jnp.min(jnp.where(subtree, delta[:, 3][None], POS_BIG), axis=1)
     d_max = jnp.max(jnp.where(subtree, delta[:, 4][None], NEG_BIG), axis=1)
     base_tree = base.tree.agg.astype(jnp.float32)

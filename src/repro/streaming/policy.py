@@ -6,7 +6,8 @@ The paper leaves re-optimization cadence as future work; here a
 ``oob_frac`` (fraction of streamed rows outside every leaf box, i.e. the
 value distribution moved) — and, when either trips, re-runs the paper's
 starred "Sampling + Discretization" (ADP) optimizer *on device*:
-``dp_monotone_jnp`` over the live reservoir pool yields fresh cuts, and
+``dp_monotone_jnp`` over (at most ``OPT_SAMPLES`` of) the live reservoir
+pool yields fresh cuts, and
 the synopsis is rebuilt through the builder's shared assembly tail
 (``synopsis_from_assignment``) with re-stratified samples.
 """
@@ -22,15 +23,45 @@ from ..core.synopsis import synopsis_from_assignment
 from .ingest import StreamingIngestor
 
 
+# Largest pool the re-optimization DP plans on: the build's own plan size
+# (``build_synopsis(opt_samples=4096)``). The DP is k sequential layers of
+# binary searches over the whole pool, so at deployment sizes (k ~ 1e3,
+# pools ~ 1e6 samples) an unbounded pool does not finish on a TPU.
+OPT_SAMPLES = 4096
+
+
+def pool_thresholds(cs, as_, valid, k: int, opt_samples: int = OPT_SAMPLES
+                    ) -> tuple[jnp.ndarray, float]:
+    """Monotone-DP thresholds over a reservoir pool.
+
+    The valid samples are sorted by coordinate; a pool of m > n =
+    max(opt_samples, k + 1) samples is thinned to the n of ranks
+    floor(i * m / n) (a systematic sample of the sorted pool). The SUM
+    oracle DP (`dp_monotone_jnp`) runs on their values and the cut ranks
+    map to value thresholds. Returns ((k-1,) thresholds, sample-space max
+    variance)."""
+    valid = np.asarray(valid).reshape(-1)
+    m = int(valid.sum())
+    if m < k + 1:
+        raise ValueError(
+            f"reservoir pool too small to re-optimize: {m} < {k + 1}")
+    cs = jnp.asarray(cs).reshape(-1)
+    as_ = jnp.asarray(as_).reshape(-1)
+    order = jnp.argsort(jnp.where(jnp.asarray(valid), cs, jnp.inf))[:m]
+    n = min(m, max(int(opt_samples), k + 1))
+    if n < m:
+        order = order[(np.arange(n, dtype=np.int64) * m) // n]
+    cuts, vmax = dp_mod.dp_monotone_jnp(as_[order], k)
+    thr = dp_mod.cuts_to_thresholds_jnp(cs[order], cuts)
+    return thr, float(vmax)
+
+
 def reoptimize_cuts(ing: StreamingIngestor, k: int | None = None
                     ) -> tuple[jnp.ndarray, float]:
-    """On-device re-partitioning: DP over the live reservoir pool.
-
-    Sorts the valid reservoir samples by coordinate, runs the jit-able
-    monotone DP (`dp_monotone_jnp`, SUM oracle) and maps the cut ranks to
-    value-space thresholds. Returns ((k-1,) thresholds, sample-space max
-    variance). 1-D synopses only — KD synopses re-optimize through
-    ``build_synopsis(method='kd')``.
+    """On-device re-partitioning: DP over the live reservoir pool
+    (:func:`pool_thresholds`, at most ``OPT_SAMPLES`` of it). Returns
+    ((k-1,) thresholds, sample-space max variance). 1-D synopses only —
+    KD synopses re-optimize through ``build_synopsis(method='kd')``.
 
     Caveat: the pooled reservoir is a *per-stratum equal-capacity* sample,
     not a uniform sample of the current dataset — strata whose population
@@ -44,19 +75,9 @@ def reoptimize_cuts(ing: StreamingIngestor, k: int | None = None
     if base.d != 1:
         raise ValueError("on-device re-optimization supports 1-D synopses; "
                          "rebuild KD synopses with build_synopsis(method='kd')")
-    k = k or base.num_leaves
     state = ing.state
-    valid = np.asarray(state.sample_valid).reshape(-1)
-    m = int(valid.sum())
-    if m < k + 1:
-        raise ValueError(f"reservoir pool too small to re-optimize: {m} < {k + 1}")
-    cs = state.sample_c.reshape(-1)
-    as_ = state.sample_a.reshape(-1)
-    order = jnp.argsort(jnp.where(jnp.asarray(valid), cs, jnp.inf))[:m]
-    c_sorted = cs[order]
-    cuts, vmax = dp_mod.dp_monotone_jnp(as_[order], k)
-    thr = dp_mod.cuts_to_thresholds_jnp(c_sorted, cuts)
-    return thr, float(vmax)
+    return pool_thresholds(state.sample_c, state.sample_a,
+                           state.sample_valid, k or base.num_leaves)
 
 
 def reoptimize(ing: StreamingIngestor, c, a, *, k: int | None = None,
@@ -141,4 +162,5 @@ class DriftPolicy:
         return reoptimize(ing, c, a, **kw)
 
 
-__all__ = ["DriftPolicy", "reoptimize_cuts", "reoptimize"]
+__all__ = ["DriftPolicy", "OPT_SAMPLES", "pool_thresholds", "reoptimize_cuts",
+           "reoptimize"]

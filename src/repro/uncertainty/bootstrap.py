@@ -14,7 +14,7 @@ heavy.
 Two execution strategies produce bit-identical replicates (tested):
 
 * **fused** (the default, ``CIConfig(boot_fused=True)``): one
-  ``bootstrap_moments`` registry op emits the whole (R, Q, k, 3)
+  ``bootstrap_moments`` registry op emits the whole (R, 3, Q, k)
   replicate-moment block from a single pass over the sample arrays — the
   Pallas megakernel on the ``pallas`` backend (``kernels/bootstrap.py``),
   a replicate-tiled broadcast-reduce on ``jnp``, the per-replicate oracle
@@ -77,7 +77,7 @@ def _draw_weights(key, r, shape):
 def _scan_moments(syn, queries, key, n_boot, backend_name):
     """The reference strategy: one weighted-moments op per replicate inside
     ``lax.scan`` — R passes over the samples. Returns the replicate-moment
-    block ((R, Q, k, 3) f32) and the resampled sizes K* ((R, k) f32)."""
+    block ((R, 3, Q, k) f32) and the resampled sizes K* ((R, k) f32)."""
     be = get_backend(backend_name)
 
     def step(carry, r):
@@ -88,7 +88,7 @@ def _scan_moments(syn, queries, key, n_boot, backend_name):
             queries.lo, queries.hi)
         # K* is a sum of small integers — exact in f32 in any order, so it
         # is safe to compute it per replicate here and batched below.
-        return carry, (jnp.stack([w_pred, ws_sum, ws_sumsq], axis=-1),
+        return carry, (jnp.stack([w_pred, ws_sum, ws_sumsq], axis=0),
                        jnp.sum(w, axis=-1))
 
     _, (mom, k_star) = jax.lax.scan(step, 0, jnp.arange(n_boot))
@@ -107,21 +107,21 @@ def _fused_moments(syn, queries, key, n_boot, backend_name):
     W = jnp.where(syn.sample_valid[None], W, 0.0)
     mom = be.bootstrap_moments(syn.sample_c, syn.sample_a,
                                syn.sample_valid, W,
-                               queries.lo, queries.hi)      # (R, Q, k, 3)
+                               queries.lo, queries.hi)      # (R, 3, Q, k)
     return mom, jnp.sum(W, axis=-1)
 
 
 def _replicates(syn, art, queries, key, kinds, n_boot, normalize,
                 backend_name, fused):
     """(R, K, Q) replicate estimates. The two strategies differ ONLY in how
-    the (R, Q, k, 3) moment block is produced; the estimate epilogue below
+    the (R, 3, Q, k) moment block is produced; the estimate epilogue below
     is one shared replicate-batched program, so fused-vs-scan bit-identity
     reduces to the moment ops' (tested per backend) — identical epilogue
     code on identical inputs cannot diverge through fusion-context
     differences."""
     strategy = _fused_moments if fused else _scan_moments
     mom, k_star = strategy(syn, queries, key, n_boot, backend_name)
-    w_pred, ws_sum = mom[..., 0], mom[..., 1]               # (R, Q, k)
+    w_pred, ws_sum = mom[:, 0], mom[:, 1]                   # (R, Q, k)
     Ni = syn.n_rows.astype(jnp.float32)
     if normalize == "hajek":
         scale = (Ni / jnp.maximum(k_star, 1.0))[:, None, :]  # (R, 1, k)

@@ -8,6 +8,8 @@ import subprocess
 import sys
 import textwrap
 
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
 SCRIPT = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
@@ -19,7 +21,10 @@ SCRIPT = textwrap.dedent("""
     from repro.core.types import QueryBatch
 
     assert len(jax.devices()) == 8
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    # make_mesh's default Explicit axes: the distributed entry points must
+    # accept the meshes users build this way
+    mesh = jax.make_mesh((4, 2), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Explicit,) * 2)
 
     rng = np.random.default_rng(0)
     n, k = 65536, 32
@@ -79,7 +84,7 @@ SCRIPT = textwrap.dedent("""
 def test_distributed_pass_subprocess():
     env = dict(os.environ, PYTHONPATH="src")
     r = subprocess.run([sys.executable, "-c", SCRIPT], env=env,
-                       capture_output=True, text=True, cwd="/root/repo",
+                       capture_output=True, text=True, cwd=REPO,
                        timeout=600)
     assert r.returncode == 0, r.stderr[-3000:]
     for tag in ("BUILD_OK", "SERVE_Q_OK", "SERVE_S_OK", "SERVE_RAGGED_OK"):

@@ -258,6 +258,31 @@ def test_init_sharded_state_split_roundtrip():
     assert np.all(np.asarray(st.seen) >= np.asarray(st.k_per_leaf))
 
 
+def test_split_rows_deals_every_run_evenly():
+    """Ordered batches (time-ordered appends) must reach every shard in
+    equal shares: each shard keeps its own reservoir slice of a stratum,
+    so any contiguous run of rows -- one stratum's rows in a batch sorted
+    by the predicate column -- lands within one row of evenly on every
+    shard, and the real rows are dealt exactly once (ragged tail
+    masked)."""
+    import jax.numpy as jnp
+    from repro.sharded import split_rows
+
+    b, D = 37, 4
+    a = jnp.arange(b, dtype=jnp.float32)
+    c = a[:, None]
+    csh, ash, mask = split_rows(c, a, D)
+    assert csh.shape == (D, 10, 1) and ash.shape == mask.shape == (D, 10)
+    real = np.asarray(ash)[np.asarray(mask)]
+    np.testing.assert_array_equal(np.sort(real), np.arange(b))
+    np.testing.assert_array_equal(np.asarray(csh)[..., 0], np.asarray(ash))
+    for lo, hi in ((0, 9), (5, 30), (12, 37)):
+        run = np.asarray(mask) & (np.asarray(ash) >= lo) \
+            & (np.asarray(ash) < hi)
+        per_shard = run.sum(axis=1)
+        assert per_shard.max() - per_shard.min() <= 1, (lo, hi, per_shard)
+
+
 def test_build_sharded_exact_one_device():
     """Sharded build on the default (1-device) mesh: exact aggregates,
     exact boxes, full reservoirs — cross-checked against numpy."""

@@ -247,6 +247,42 @@ def test_drift_policy_triggers_and_reoptimize_adapts():
     assert np.median(rel) < 0.1
 
 
+def test_reoptimize_cuts_plans_on_a_bounded_pool():
+    """The re-optimization DP plans on at most ``opt_samples`` of the
+    reservoir pool: a systematic sample of the coordinate-sorted pool. A
+    pool within the bound is used whole."""
+    from repro.core import dp as dp_mod
+    from repro.streaming.policy import (OPT_SAMPLES, pool_thresholds,
+                                        reoptimize_cuts)
+    syn, _, _ = _base(n=20000, k=16, sample_budget=640, int_vals=False)
+    ing = StreamingIngestor(syn, seed=3)
+    st = ing.state
+    valid = np.asarray(st.sample_valid).reshape(-1)
+    cs = np.asarray(st.sample_c).reshape(-1)[valid]
+    as_ = np.asarray(st.sample_a).reshape(-1)[valid]
+    order = np.argsort(cs, kind="stable")
+    m = order.size
+    assert 100 < m <= OPT_SAMPLES
+
+    def dp_thresholds(idx):
+        cuts, _ = dp_mod.dp_monotone_jnp(jnp.asarray(as_[idx]), 16)
+        return np.asarray(dp_mod.cuts_to_thresholds_jnp(
+            jnp.asarray(cs[idx]), cuts))
+
+    def plan(n):
+        thr, _ = pool_thresholds(st.sample_c, st.sample_a, st.sample_valid,
+                                 16, opt_samples=n)
+        return np.asarray(thr)
+
+    np.testing.assert_array_equal(
+        plan(100), dp_thresholds(order[(np.arange(100) * m) // 100]))
+    whole = dp_thresholds(order)
+    np.testing.assert_array_equal(plan(m), whole)
+    np.testing.assert_array_equal(np.asarray(reoptimize_cuts(ing)[0]), whole)
+    # the plan never has fewer than k + 1 samples
+    assert plan(2).shape == (15,)
+
+
 def test_updatable_synopsis_bridges_to_streaming():
     syn, c0, a0 = _base()
     upd = UpdatableSynopsis(syn, seed=1)
