@@ -185,14 +185,11 @@ class CoalescerConfig:
                           :class:`~repro.serve.Overloaded`.
     ``max_queue_depth``   global queued-request bound; submissions past it
                           are shed regardless of tenant.
-    ``wait_window``       per-tenant queue-wait samples kept for the
-                          p50/p95 accounting in ``stats()``.
     """
     tick_ms: float = 2.0
     shape_classes: tuple[int, ...] = (8, 32, 128)
     max_outstanding: int = 8
     max_queue_depth: int = 256
-    wait_window: int = 1024
 
     def __post_init__(self):
         object.__setattr__(self, "shape_classes",
@@ -213,8 +210,6 @@ class CoalescerConfig:
             raise ValueError("max_outstanding must be >= 1")
         if self.max_queue_depth < 1:
             raise ValueError("max_queue_depth must be >= 1")
-        if self.wait_window < 1:
-            raise ValueError("wait_window must be >= 1")
         return self
 
     def padded_size(self, q: int) -> int:

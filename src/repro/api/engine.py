@@ -30,15 +30,23 @@ as the synopsis shapes do).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from collections import OrderedDict
 
 import jax
+from jax.profiler import annotate_function
 
 from ..core.types import QueryBatch, QueryResult
 from ..engine import executor as _executor
 from ..engine.assemble import _answer_jit
 from ..kernels.registry import get_backend
 from .config import ServingConfig, CIConfig, as_ci_config
+
+# Profiler span names; the benchmark's trace reduction reads them by name.
+SPAN_PREPARE = "repro.engine.prepare"
+SPAN_CALL = "repro.engine.call"
+SPAN_COMPILE = "repro.engine.compile"
+
 
 class _Unset:
     """Sentinel distinguishing 'inherit the engine's CIConfig' from an
@@ -283,12 +291,14 @@ class PreparedQuery:
         if not same:
             self._aot = None
 
+    @functools.partial(annotate_function, name=SPAN_COMPILE)
     def _build_aot(self, args) -> None:
         # A compile failure raises: serving must not silently leave the
         # compiled path it was prepared for.
         self._aot = self._fn.lower(*args, **self._statics).compile()
         self._engine._stats["aot_compiles"] += 1
 
+    @functools.partial(annotate_function, name=SPAN_CALL)
     def __call__(self, queries: QueryBatch,
                  plan_masks=None) -> dict[str, QueryResult]:
         if (plan_masks is not None) != self.has_plan:
@@ -544,8 +554,8 @@ class PassEngine:
         compiled for)/fused_serves (calls answered through the fused
         bootstrap megakernel path) plus current entry count and source
         epoch. When a :class:`repro.serve.RequestCoalescer` is attached to
-        this engine, its snapshot (dispatch amortization, per-tenant
-        served counts and queue-wait percentiles) rides along under the
+        this engine, its snapshot (dispatch amortization, queue-wait
+        counters, per-tenant served counts) rides along under the
         ``"coalescer"`` key."""
         out = dict(self._stats, entries=len(self._cache), epoch=self.epoch)
         if self._coalescer is not None:
@@ -575,6 +585,7 @@ class PassEngine:
         return faults
 
     # -- serving -----------------------------------------------------------
+    @functools.partial(annotate_function, name=SPAN_PREPARE)
     def prepare(self, queries_or_shape, *, kinds=None, ci=_UNSET,
                 serving: ServingConfig | None = None) -> PreparedQuery:
         """Pin a (batch shape x config) serving entry and return the handle.
@@ -757,6 +768,7 @@ class PassEngine:
                     "was built against; rebuild with build_join_synopsis "
                     "to join a different dimension relation")
 
+    @functools.partial(annotate_function, name=SPAN_PREPARE)
     def prepare_join(self, queries_or_shape, *, kinds=None, ci=_UNSET,
                      serving: ServingConfig | None = None
                      ) -> PreparedJoinQuery:

@@ -38,10 +38,17 @@ Admission control sheds load *at submit time*: a tenant past its
 ``max_outstanding`` budget, or any submission past the global
 ``max_queue_depth``, raises the typed :class:`Overloaded` error instead
 of growing an unbounded queue. Per-tenant accounting (requests, queries
-served, shed counts, queue-wait p50/p95) and dispatch amortization are
-surfaced through ``coalescer.stats()`` — and through
-``engine.stats()["coalescer"]``, since constructing a coalescer attaches
-it to its engine.
+served, shed counts), the coalescer-wide queue wait (``queue_wait_ns``
+over ``queue_waits`` requests) and dispatch amortization are surfaced
+through ``coalescer.stats()`` — and through ``engine.stats()["coalescer"]``,
+since constructing a coalescer attaches it to its engine.
+
+The tick thread's work carries profiler spans
+(``jax.profiler.TraceAnnotation``, the ``SPAN_*`` names below), which join
+the device ops on the profiler trace's clock: per tick, per dispatch, and
+inside a dispatch its mux, pull and resolve (the engine's prepare, call
+and compile spans nest between them). With no profiler running a span
+costs about a microsecond.
 
 Streaming epoch invalidation: an ingest epoch bump must drain in-flight
 buckets before the prepared entries re-pin onto the fresh delta merge.
@@ -59,14 +66,16 @@ and the bench use.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import threading
 import time
-from collections import OrderedDict, deque
+from collections import OrderedDict
 from concurrent.futures import Future
 
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation, annotate_function
 
 from ..api.config import ServingConfig, CIConfig, CoalescerConfig
 from ..api.engine import PassEngine, _UNSET
@@ -75,6 +84,14 @@ from ..core.types import QueryBatch, QueryResult
 # Empty-predicate pad rows: lo > hi matches no row and no stratum. Finite
 # (not inf) so distance arithmetic in every backend stays NaN-free.
 PAD_LO, PAD_HI = 3.0e38, -3.0e38
+
+# Profiler span names; the benchmark's trace reduction reads them by name.
+SPAN_SUBMIT = "repro.serve.submit"
+SPAN_TICK = "repro.serve.tick"
+SPAN_DISPATCH = "repro.serve.dispatch"
+SPAN_MUX = "repro.serve.mux"
+SPAN_PULL = "repro.serve.pull"
+SPAN_RESOLVE = "repro.serve.resolve"
 
 
 class Overloaded(RuntimeError):
@@ -112,28 +129,23 @@ class _Pending:
 
 
 class _TenantAccount:
-    """Per-tenant serving telemetry (bounded queue-wait window)."""
+    """Per-tenant serving telemetry."""
 
-    def __init__(self, window: int):
+    def __init__(self):
         self.requests = 0
         self.queries = 0
         self.shed = 0
         self.outstanding = 0
-        self.waits = deque(maxlen=window)
 
     def snapshot(self) -> dict:
-        waits = np.asarray(self.waits, np.float64)
-        p50, p95 = ((float(np.percentile(waits, 50) * 1e3),
-                     float(np.percentile(waits, 95) * 1e3))
-                    if waits.size else (0.0, 0.0))
         return {"requests": self.requests, "queries": self.queries,
-                "shed": self.shed, "outstanding": self.outstanding,
-                "wait_p50_ms": p50, "wait_p95_ms": p95}
+                "shed": self.shed, "outstanding": self.outstanding}
 
 
 _QR_FIELDS = tuple(f.name for f in dataclasses.fields(QueryResult))
 
 
+@functools.partial(annotate_function, name=SPAN_PULL)
 def _pull_host(results: dict[str, QueryResult]) -> dict[str, list]:
     """One synchronizing device->host pull of the whole batch result,
     flattened to ``{kind: [field arrays in _QR_FIELDS order]}``."""
@@ -166,7 +178,8 @@ class RequestCoalescer:
                        "dispatches": 0, "ticks": 0, "coalesced_rows": 0,
                        "padded_rows": 0, "epoch_drains": 0, "dedup_hits": 0,
                        "degraded_served": 0, "failed": 0,
-                       "driver_errors": 0, "last_driver_error": None}
+                       "driver_errors": 0, "last_driver_error": None,
+                       "queue_wait_ns": 0, "queue_waits": 0}
         # EWMA of device dispatch latency — the deadline router compares
         # a request's remaining budget against this prediction.
         self._dispatch_ewma_ms = 0.0
@@ -186,10 +199,10 @@ class RequestCoalescer:
     def _account(self, tenant) -> _TenantAccount:
         acct = self._tenants.get(tenant)
         if acct is None:
-            acct = self._tenants[tenant] = _TenantAccount(
-                self.config.wait_window)
+            acct = self._tenants[tenant] = _TenantAccount()
         return acct
 
+    @functools.partial(annotate_function, name=SPAN_SUBMIT)
     def submit(self, tenant, queries: QueryBatch, *, kinds=None, ci=_UNSET,
                serving: ServingConfig | None = None,
                join: bool = False,
@@ -280,6 +293,7 @@ class RequestCoalescer:
         self._generation = eng._generation
 
     # -- dispatch ----------------------------------------------------------
+    @functools.partial(annotate_function, name=SPAN_MUX)
     def _mux(self, group: list[_Pending], padded_b: int, d: int
              ) -> QueryBatch:
         """Build the padded cross-tenant batch. Device-resident requests
@@ -329,14 +343,12 @@ class RequestCoalescer:
         except Exception as exc:
             p.future.set_exception(exc)
             res = None
-        now = time.perf_counter()
         with self._lock:
             acct = self._account(p.tenant)
             if count_outstanding:
                 acct.outstanding -= 1
             if res is not None:
                 acct.queries += p.rows
-                acct.waits.append(now - p.t_submit)
                 self._stats["served"] += 1
                 self._stats["degraded_served"] += 1
             else:
@@ -347,58 +359,69 @@ class RequestCoalescer:
 
     def _dispatch(self, group: list[_Pending], padded_b: int,
                   serving: ServingConfig, ci: CIConfig | None) -> None:
-        """Serve one padded batch (one device dispatch) and demux."""
+        """Serve one padded batch (one device dispatch) and demux. Every
+        request riding it, dedup riders included, adds its wait since
+        submit to the queue-wait counters."""
         t0 = time.perf_counter()
         d = int(group[0].queries.lo.shape[1])
         rows = sum(p.rows for p in group)
         pad = padded_b - rows
         everyone = [q for p in group for q in (p, *p.dups)]
-        try:
-            if group[0].join:
-                prepared = self.engine.prepare_join(
-                    (padded_b, d), serving=serving, ci=ci)
-            else:
-                prepared = self.engine.prepare((padded_b, d),
-                                               serving=serving, ci=ci)
-            results = prepared(self._mux(group, padded_b, d))
-            # One synchronizing pull of the whole result pytree; the
-            # per-request demux below is zero-copy numpy views.
-            host = _pull_host(results)
-        except Exception as exc:                  # deliver, don't swallow
-            for p in everyone:
-                p.future.set_exception(exc)
-            self._finish(everyone, served=False)
-            return
-        dt_ms = (time.perf_counter() - t0) * 1e3
         with self._lock:
-            self._dispatched_since_drain = True
-            self._stats["dispatches"] += 1
-            self._stats["coalesced_rows"] += rows
-            self._stats["padded_rows"] += pad
-            self._dispatch_ewma_ms = (
-                dt_ms if self._dispatch_ewma_ms == 0.0
-                else 0.7 * self._dispatch_ewma_ms + 0.3 * dt_ms)
-        off = 0
-        for p in group:
-            p.future.set_result(_slice_results(host, off, p.rows))
-            # Deduped duplicates demux the same row range — each gets its
-            # own fresh view dict, so tenants never share result objects.
-            for q in p.dups:
-                q.future.set_result(_slice_results(host, off, q.rows))
-            off += p.rows
-        self._finish(everyone, served=True)
+            seq = self._stats["dispatches"]
+            self._stats["queue_waits"] += len(everyone)
+            self._stats["queue_wait_ns"] += sum(
+                int((t0 - p.t_submit) * 1e9) for p in everyone)
+        with TraceAnnotation(SPAN_DISPATCH, dispatch=seq, rows=rows,
+                             padded=padded_b):
+            try:
+                if group[0].join:
+                    prepared = self.engine.prepare_join(
+                        (padded_b, d), serving=serving, ci=ci)
+                else:
+                    prepared = self.engine.prepare((padded_b, d),
+                                                   serving=serving, ci=ci)
+                results = prepared(self._mux(group, padded_b, d))
+                # One synchronizing pull of the whole result pytree; the
+                # per-request demux below is zero-copy numpy views.
+                host = _pull_host(results)
+            except Exception as exc:              # deliver, don't swallow
+                with TraceAnnotation(SPAN_RESOLVE):
+                    for p in everyone:
+                        p.future.set_exception(exc)
+                    self._finish(everyone, served=False)
+                return
+            dt_ms = (time.perf_counter() - t0) * 1e3
+            with TraceAnnotation(SPAN_RESOLVE):
+                with self._lock:
+                    self._dispatched_since_drain = True
+                    self._stats["dispatches"] += 1
+                    self._stats["coalesced_rows"] += rows
+                    self._stats["padded_rows"] += pad
+                    self._dispatch_ewma_ms = (
+                        dt_ms if self._dispatch_ewma_ms == 0.0
+                        else 0.7 * self._dispatch_ewma_ms + 0.3 * dt_ms)
+                off = 0
+                for p in group:
+                    p.future.set_result(_slice_results(host, off, p.rows))
+                    # Deduped duplicates demux the same row range — each
+                    # gets its own fresh view dict, so tenants never share
+                    # result objects.
+                    for q in p.dups:
+                        q.future.set_result(_slice_results(host, off, q.rows))
+                    off += p.rows
+                self._finish(everyone, served=True)
 
     def _finish(self, group: list[_Pending], served: bool) -> None:
-        now = time.perf_counter()
         with self._lock:
             for p in group:
                 acct = self._account(p.tenant)
                 acct.outstanding -= 1
                 if served:
                     acct.queries += p.rows
-                    acct.waits.append(now - p.t_submit)
                     self._stats["served"] += 1
 
+    @functools.partial(annotate_function, name=SPAN_TICK)
     def tick(self) -> int:
         """One coalescing pass: drain on an epoch bump, bucket everything
         queued, dispatch each bucket's padded batches, demux. Returns the
@@ -521,9 +544,11 @@ class RequestCoalescer:
     def stats(self) -> dict:
         """Coalescer snapshot: overall counters (submitted/served/shed,
         device ``dispatches`` vs ``coalesced_rows`` — the amortization —
-        pad overhead, epoch drains) plus ``tenants``: per-tenant requests,
-        queries served, shed count, outstanding, and queue-wait p50/p95
-        in milliseconds over the last ``wait_window`` served requests."""
+        pad overhead, epoch drains, and ``queue_wait_ns`` summed over
+        ``queue_waits`` requests from submit to the start of their
+        dispatch; tier-0 answers have no dispatch and do not count) plus
+        ``tenants``: per-tenant requests, queries served, shed count and
+        outstanding."""
         with self._lock:
             out = dict(self._stats, queue_depth=len(self._queue))
             out["tenants"] = {t: a.snapshot()

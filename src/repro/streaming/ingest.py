@@ -33,11 +33,16 @@ from functools import partial
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation, annotate_function
 
 from ..core.types import Synopsis, AGG_COUNT
 from ..kernels import route as _route
 from ..kernels.ref import NEG_BIG, POS_BIG
 from ..kernels.registry import get_backend
+
+# Profiler span names; the benchmark's trace reduction reads them by name.
+SPAN_INGEST_BATCH = "repro.ingest.batch"
+SPAN_INGEST_MERGE = "repro.ingest.merge"
 
 
 @partial(jax.tree_util.register_dataclass,
@@ -364,6 +369,7 @@ class StreamingIngestor:
         return self._epoch
 
     # -- ingestion -----------------------------------------------------------
+    @partial(annotate_function, name=SPAN_INGEST_BATCH)
     def ingest(self, c_rows, a_vals, u=None) -> "StreamingIngestor":
         """Ingest a (B, d) coordinate batch + (B,) value batch.
 
@@ -427,9 +433,11 @@ class StreamingIngestor:
         """Delta-merged serving synopsis (cached; device-only combine)."""
         if self._merged is None:
             from .delta import merge_synopsis
-            self._merged = merge_synopsis(self.base, self.state,
-                                          self._subtree,
-                                          total_rows=self.total_rows)
+            # total_rows reads the quarantine counter back to the host.
+            with TraceAnnotation(SPAN_INGEST_MERGE):
+                self._merged = merge_synopsis(self.base, self.state,
+                                              self._subtree,
+                                              total_rows=self.total_rows)
         return self._merged
 
 

@@ -258,8 +258,8 @@ def test_admission_global_queue_depth_sheds_typed():
 
 
 def test_accounting_through_engine_stats():
-    """Per-tenant accounting (queries served, dispatch amortization,
-    wait percentiles) is reachable from engine.stats()."""
+    """Per-tenant accounting (queries served), dispatch amortization and
+    the queue-wait counters are reachable from engine.stats()."""
     c, a, syn = _make(k=4, n=3000)
     eng = PassEngine(syn, serving=ServingConfig(kinds=("sum",)))
     co = RequestCoalescer(eng, CoalescerConfig(shape_classes=(16,)))
@@ -273,7 +273,7 @@ def test_accounting_through_engine_stats():
     assert s["coalesced_rows"] == 16 and s["padded_rows"] == 0
     alice = s["tenants"]["alice"]
     assert alice["queries"] == 12 and alice["requests"] == 4
-    assert alice["wait_p95_ms"] >= alice["wait_p50_ms"] >= 0.0
+    assert s["queue_waits"] == 5 and s["queue_wait_ns"] > 0
     assert s["tenants"]["bob"]["queries"] == 4
     # buckets reuse ONE prepared executable: a second wave of the same
     # shapes is all plan-cache hits
@@ -282,6 +282,28 @@ def test_accounting_through_engine_stats():
         co.submit("alice", random_queries(c, 5, seed=20 + i))
     co.tick()
     assert eng.stats()["misses"] == misses0
+
+
+def test_queue_wait_counters_cover_every_dispatched_request():
+    """queue_wait_ns sums each dispatched request's wait from submit to the
+    start of its dispatch, over queue_waits requests: a dedup rider counts,
+    a tier-0 answer (no dispatch) does not."""
+    c, a, syn = _make(k=4, n=3000)
+    eng = PassEngine(syn, serving=ServingConfig(kinds=("sum",)))
+    co = RequestCoalescer(eng, CoalescerConfig(shape_classes=(16,)))
+    before = co.stats()
+    qs = random_queries(c, 3, seed=1)
+    co.submit("a", qs)
+    co.submit("b", qs)                          # rides a's dispatch
+    co.submit("c", random_queries(c, 4, seed=2))
+    co.submit("d", random_queries(c, 2, seed=3), deadline_ms=0.0)
+    time.sleep(0.005)
+    co.tick()
+    s = co.stats()
+    assert s["dedup_hits"] == 1 and s["degraded_served"] == 1
+    assert s["served"] == 4 and s["dispatches"] == 1
+    assert s["queue_waits"] - before["queue_waits"] == 3
+    assert s["queue_wait_ns"] - before["queue_wait_ns"] >= 3 * 5_000_000
 
 
 def test_coalescer_config_validation():
