@@ -25,10 +25,16 @@ layer is fastest at:
    masked lane, and never perturb real rows (every per-query artifact is
    row-independent; bit-identity is asserted in tests and in the
    ``bench_coalescer`` gate).
-3. **Demux** — each kind's :class:`QueryResult` is pulled to the host
-   once per dispatch (one synchronizing ``device_get`` of the whole
-   result pytree) and sliced into per-request row ranges as zero-copy
-   numpy views, delivered through per-request
+3. **Demux** — a dispatch's whole result (every kind's
+   :class:`QueryResult`) reaches the host in ONE device-to-host transfer:
+   a small jitted pack executable, cached per layout and run after the
+   engine's program, concatenates the present fields of each dtype into
+   one device array, which is copied once. Each synchronizing copy costs
+   about 0.5 ms on a v5e whatever its size, far more than a dispatch's
+   device work, so the number of copies sets the pull's cost;
+   ``stats()["pull_transfers"]`` counts them. Every field is a zero-copy
+   view of the host buffer, sliced into per-request row ranges as
+   zero-copy numpy views and delivered through per-request
    :class:`concurrent.futures.Future`\\ s. Host-side demux matters: a
    lazy per-request ``jax`` slice costs one eager dispatch per field per
    request (~85x slower than the numpy views at 8 tenants x 3 kinds),
@@ -145,13 +151,58 @@ class _TenantAccount:
 _QR_FIELDS = tuple(f.name for f in dataclasses.fields(QueryResult))
 
 
+# Jitted pack executables, keyed by one dtype group's field shapes (bounded
+# LRU like the mux cache: a result's layout follows its shape class and
+# config, a handful in steady state).
+_PACK_CACHE: OrderedDict[tuple, object] = OrderedDict()
+_PACK_LOCK = threading.Lock()
+# Device-to-host transfers issued by this thread's latest pull; _dispatch
+# reads it into stats()["pull_transfers"].
+_PULLED = threading.local()
+
+
+def _pack(key: tuple):
+    """The jitted executable that flattens and concatenates one dtype
+    group's fields into a single 1-D array (an exact copy)."""
+    with _PACK_LOCK:
+        fn = _PACK_CACHE.get(key)
+        if fn is None:
+            fn = _PACK_CACHE[key] = jax.jit(
+                lambda arrs: jnp.concatenate([jnp.ravel(a) for a in arrs]))
+            if len(_PACK_CACHE) > 256:
+                _PACK_CACHE.popitem(last=False)
+        else:
+            _PACK_CACHE.move_to_end(key)
+    return fn
+
+
 @functools.partial(annotate_function, name=SPAN_PULL)
 def _pull_host(results: dict[str, QueryResult]) -> dict[str, list]:
-    """One synchronizing device->host pull of the whole batch result,
-    flattened to ``{kind: [field arrays in _QR_FIELDS order]}``."""
-    return {kind: [None if (v := getattr(r, name)) is None
-                   else np.asarray(v) for name in _QR_FIELDS]
-            for kind, r in results.items()}
+    """Pull the whole batch result to the host, flattened to ``{kind:
+    [field arrays in _QR_FIELDS order, None where absent]}``. The present
+    device fields of each dtype are packed on the device into one array
+    and copied to the host in ONE transfer (each synchronizing copy costs
+    about as much as the dispatch's device work); every field is then a
+    zero-copy view of that host buffer. Fields already on the host pass
+    through."""
+    host = {kind: [None] * len(_QR_FIELDS) for kind in results}
+    groups: dict[object, list] = {}
+    for kind, r in results.items():
+        for i, name in enumerate(_QR_FIELDS):
+            v = getattr(r, name)
+            if isinstance(v, jax.Array):
+                groups.setdefault(v.dtype, []).append((kind, i, v))
+            elif v is not None:
+                host[kind][i] = np.asarray(v)
+    for dtype, members in groups.items():
+        arrs = [v for _, _, v in members]
+        flat = np.asarray(_pack((dtype, tuple(a.shape for a in arrs)))(arrs))
+        off = 0
+        for kind, i, v in members:
+            host[kind][i] = flat[off:off + v.size].reshape(v.shape)
+            off += v.size
+    _PULLED.transfers = len(groups)
+    return host
 
 
 def _slice_results(host: dict[str, list], off: int, rows: int
@@ -179,7 +230,8 @@ class RequestCoalescer:
                        "padded_rows": 0, "epoch_drains": 0, "dedup_hits": 0,
                        "degraded_served": 0, "failed": 0,
                        "driver_errors": 0, "last_driver_error": None,
-                       "queue_wait_ns": 0, "queue_waits": 0}
+                       "queue_wait_ns": 0, "queue_waits": 0,
+                       "pull_transfers": 0}
         # EWMA of device dispatch latency — the deadline router compares
         # a request's remaining budget against this prediction.
         self._dispatch_ewma_ms = 0.0
@@ -382,9 +434,11 @@ class RequestCoalescer:
                     prepared = self.engine.prepare((padded_b, d),
                                                    serving=serving, ci=ci)
                 results = prepared(self._mux(group, padded_b, d))
-                # One synchronizing pull of the whole result pytree; the
+                # One synchronizing transfer per dtype of the result; the
                 # per-request demux below is zero-copy numpy views.
+                _PULLED.transfers = 0
                 host = _pull_host(results)
+                transfers = _PULLED.transfers
             except Exception as exc:              # deliver, don't swallow
                 with TraceAnnotation(SPAN_RESOLVE):
                     for p in everyone:
@@ -396,6 +450,7 @@ class RequestCoalescer:
                 with self._lock:
                     self._dispatched_since_drain = True
                     self._stats["dispatches"] += 1
+                    self._stats["pull_transfers"] += transfers
                     self._stats["coalesced_rows"] += rows
                     self._stats["padded_rows"] += pad
                     self._dispatch_ewma_ms = (
@@ -544,9 +599,11 @@ class RequestCoalescer:
     def stats(self) -> dict:
         """Coalescer snapshot: overall counters (submitted/served/shed,
         device ``dispatches`` vs ``coalesced_rows`` — the amortization —
-        pad overhead, epoch drains, and ``queue_wait_ns`` summed over
+        pad overhead, epoch drains, ``queue_wait_ns`` summed over
         ``queue_waits`` requests from submit to the start of their
-        dispatch; tier-0 answers have no dispatch and do not count) plus
+        dispatch — tier-0 answers have no dispatch and do not count — and
+        ``pull_transfers``, the device-to-host transfers the result pulls
+        issued: one per dispatch when the result holds one dtype) plus
         ``tenants``: per-tenant requests, queries served, shed count and
         outstanding."""
         with self._lock:

@@ -20,8 +20,10 @@ except ImportError:
 
 from repro.api import (PassEngine, ServingConfig, CIConfig, CoalescerConfig)
 from repro.core import build_synopsis, random_queries
-from repro.core.types import QueryBatch
+from repro.core.types import QueryBatch, QueryResult
+from repro.joins import build_dim_table, build_join_synopsis
 from repro.serve import RequestCoalescer, TickDriver, Overloaded
+from repro.serve import coalescer as coalescer_mod
 
 ALL_KINDS = ("sum", "count", "avg", "min", "max")
 FIELDS = ("estimate", "ci_half", "lower", "upper", "frac_rows_touched",
@@ -304,6 +306,139 @@ def test_queue_wait_counters_cover_every_dispatched_request():
     assert s["served"] == 4 and s["dispatches"] == 1
     assert s["queue_waits"] - before["queue_waits"] == 3
     assert s["queue_wait_ns"] - before["queue_wait_ns"] >= 3 * 5_000_000
+
+
+# --------------------------------------------------------------------------
+# Device-to-host pull: one transfer per dispatch
+# --------------------------------------------------------------------------
+
+def _per_field_pull(results):
+    """The pull the packed one replaced: one host copy per present field."""
+    return {kind: [None if (v := getattr(r, f)) is None else np.asarray(v)
+                   for f in coalescer_mod._QR_FIELDS]
+            for kind, r in results.items()}
+
+
+def _pull_case_results(case):
+    """A dispatch-shaped result dict for each layout the pull must take."""
+    if case == "join":
+        rng = np.random.default_rng(7)
+        c = rng.normal(size=3000).astype(np.float32)
+        a = rng.gamma(2.0, 1.0, size=3000).astype(np.float32)
+        keys = rng.integers(0, 100, size=3000).astype(np.int32)
+        dim = build_dim_table(np.arange(100, dtype=np.int32),
+                              rng.normal(size=100).astype(np.float32),
+                              num_partitions=4)
+        jsyn, _ = build_join_synopsis(c, a, keys, dim, k=8, p_u=0.3, seed=19)
+        eng = PassEngine(jsyn, ci=CIConfig(level=0.95))
+        fq = QueryBatch(lo=jnp.asarray([[-1.0], [0.0], [-0.2]], jnp.float32),
+                        hi=jnp.asarray([[0.5], [2.0], [0.3]], jnp.float32))
+        dq = QueryBatch(lo=jnp.asarray([[-0.5], [-2.0], [-1.0]], jnp.float32),
+                        hi=jnp.asarray([[2.0], [1.0], [0.0]], jnp.float32))
+        return eng.answer_join(fq, dq, kinds=("sum", "count"))
+    c, a, syn = _make(seed=3, k=8, n=8000)
+    qs = random_queries(c, 8, seed=5)
+    if case == "mixed":
+        # Two dtypes and a field already on the host: one transfer per
+        # dtype group, the host field passes through.
+        base = PassEngine(syn, serving=ServingConfig(kinds=("sum",))
+                          ).answer(qs)["sum"]
+        return {"sum": base, "count": QueryResult(
+            estimate=jnp.arange(8, dtype=jnp.int32),
+            ci_half=np.full(8, 0.5, np.float32), lower=base.lower,
+            upper=base.upper, frac_rows_touched=jnp.ones((2, 4), jnp.int32))}
+    ci = {"plain": None, "clt": CIConfig(level=0.95),
+          "bootstrap": CIConfig(method="bootstrap", n_boot=16)}[case]
+    eng = PassEngine(syn, serving=ServingConfig(kinds=("sum", "count", "avg")),
+                     ci=ci)
+    return eng.answer(qs)
+
+
+@pytest.mark.parametrize("case, transfers", [
+    ("plain", 1), ("clt", 1), ("bootstrap", 1), ("join", 1), ("mixed", 2)])
+def test_packed_pull_matches_per_field_pull(case, transfers):
+    """The one-transfer pull returns, field for field, the arrays the
+    per-field pull returned: same dtype, shape and bits, None in the same
+    places, in one transfer per dtype of the result."""
+    results = _pull_case_results(case)
+    want = _per_field_pull(results)
+    coalescer_mod._PULLED.transfers = 0
+    got = coalescer_mod._pull_host(results)
+    assert coalescer_mod._PULLED.transfers == transfers
+    assert list(got) == list(want)
+    for kind in want:
+        assert len(got[kind]) == len(want[kind]) == len(FIELDS)
+        for f, g, w in zip(FIELDS, got[kind], want[kind]):
+            if w is None:
+                assert g is None, (kind, f)
+                continue
+            assert isinstance(g, np.ndarray), (kind, f)
+            assert g.dtype == w.dtype and g.shape == w.shape, (kind, f)
+            assert np.array_equal(g, w) and g.tobytes() == w.tobytes(), \
+                (kind, f)
+
+
+def test_pull_transfers_one_per_dispatch():
+    """stats()["pull_transfers"] grows by exactly one per dispatch, over
+    ticks of one and of several dispatches."""
+    c, a, syn = _make(k=8, n=6000)
+    eng = PassEngine(syn, serving=ServingConfig(kinds=("sum", "count",
+                                                       "avg")), ci=0.95)
+    co = RequestCoalescer(eng, CoalescerConfig(shape_classes=(4, 8)))
+    assert co.stats()["pull_transfers"] == 0
+    total = 0
+    for tick, sizes in enumerate([[3], [2, 2, 3], [4, 4, 7, 1], [8]]):
+        for i, q in enumerate(sizes):
+            co.submit(f"t{i}", random_queries(c, q, seed=10 * tick + i))
+        n = co.tick()
+        total += n
+        s = co.stats()
+        assert s["dispatches"] == total
+        assert s["pull_transfers"] == total
+    assert total > 4                            # some tick dispatched twice
+
+
+def test_request_slices_are_views_of_their_own_rows(monkeypatch):
+    """Every request's result fields are zero-copy views of its own row
+    range of the one host buffer the dispatch pulled."""
+    c, a, syn = _make(k=8, n=6000)
+    eng = PassEngine(syn, serving=ServingConfig(kinds=("sum", "avg")),
+                     ci=0.95)
+    co = RequestCoalescer(eng, CoalescerConfig(shape_classes=(16,)))
+    pulled = []
+    orig = coalescer_mod._pull_host
+
+    def spy(results):
+        host = orig(results)
+        pulled.append(host)
+        return host
+
+    monkeypatch.setattr(coalescer_mod, "_pull_host", spy)
+    sizes = [3, 5, 1, 6]
+    futs = [co.submit(f"t{i}", random_queries(c, q, seed=i))
+            for i, q in enumerate(sizes)]
+    assert co.tick() == 1 and len(pulled) == 1
+    host = pulled[0]
+    owners = {id(arr.base) for arrs in host.values()
+              for arr in arrs if arr is not None}
+    assert len(owners) == 1                     # one buffer, one transfer
+    got = [f.result(timeout=0) for f in futs]
+    for kind, arrs in host.items():
+        for i, f in enumerate(FIELDS):
+            full = arrs[i]
+            if full is None:
+                assert all(getattr(r[kind], f) is None for r in got)
+                continue
+            off = 0
+            for j, (res, rows) in enumerate(zip(got, sizes)):
+                v = getattr(res[kind], f)
+                assert v.shape == (rows,)
+                assert np.shares_memory(v, full)
+                assert (v.__array_interface__["data"][0]
+                        == full[off:].__array_interface__["data"][0])
+                for other in got[j + 1:]:
+                    assert not np.shares_memory(v, getattr(other[kind], f))
+                off += rows
 
 
 def test_coalescer_config_validation():
