@@ -28,6 +28,8 @@ number whose limit is null is printed and not compared):
 """
 from __future__ import annotations
 
+import time
+
 import numpy as np
 
 ULP = 2.0 ** -24
@@ -38,32 +40,74 @@ def _rounding(k: int) -> float:
     return (2 * k + 4) * ULP
 
 
+class IndexMismatch(RuntimeError):
+    """The reference's index disagrees with the plain scan."""
+
+
 class Truth:
     """Exact answers of predicate batches over the base rows plus the first
-    ``j`` stream batches (pool batch ``i % P`` for the ``i``-th)."""
+    ``j`` stream batches (pool batch ``i % P`` for the ``i``-th).
 
-    def __init__(self, c, a, stream=None):
+    With more than one predicate column the answers come from an index
+    (``f64.Grid``); every call re-answers a subset of its predicates,
+    drawn from ``seed``, by the plain scan, and raises ``IndexMismatch``
+    unless every count is equal and every sum within ``SELF_CHECK_RTOL``."""
+
+    SELF_CHECKED = 32
+    SELF_CHECK_RTOL = 1e-12
+
+    def __init__(self, c, a, stream=None, seed: int = 0):
         from bench.reference import f64
-        self.base = f64.Table(c, a)
-        self.batches = []
+        self.tables = [(f64.Table(c, a), c, a)]
         if stream is not None:
             for p in range(stream.pool_batches):
                 bc, ba = stream.batch(p)
-                self.batches.append(f64.Table(bc, ba))
+                self.tables.append((f64.Table(bc, ba), bc, ba))
+        self.rng = np.random.default_rng(seed)
+        self.self_checked = 0
 
     def parts(self, lo, hi):
-        """(base sum, base count, per-pool-batch sums (P, Q), counts)."""
-        s, n = self.base.sum_count(lo, hi)
-        ps = np.zeros((len(self.batches), s.size))
-        pn = np.zeros_like(ps)
-        for p, t in enumerate(self.batches):
-            ps[p], pn[p] = t.sum_count(lo, hi)
-        return s, n, ps, pn
+        """(base sum, base count, per-pool-batch sums (P, Q), counts); each
+        distinct predicate is answered once."""
+        lo, hi = np.asarray(lo), np.asarray(hi)
+        key = np.ascontiguousarray(np.concatenate([lo, hi], axis=1))
+        rows = key.view(np.dtype((np.void, key.dtype.itemsize
+                                  * key.shape[1]))).ravel()
+        _, first, back = np.unique(rows, return_index=True,
+                                   return_inverse=True)
+        lo, hi = lo[first], hi[first]
+        out = [t.sum_count(lo, hi) for t, _c, _a in self.tables]
+        if lo.shape[1] > 1:
+            self._self_check(lo, hi, out)
+        s, n = out[0]
+        ps = np.array([o[0] for o in out[1:]]).reshape(-1, s.size)
+        pn = np.array([o[1] for o in out[1:]]).reshape(-1, s.size)
+        return s[back], n[back], ps[:, back], pn[:, back]
+
+    def _self_check(self, lo, hi, out) -> None:
+        from bench.reference import f64
+        sub = np.sort(self.rng.choice(lo.shape[0], min(
+            self.SELF_CHECKED, lo.shape[0]), replace=False))
+        for (_t, c, a), (s, n) in zip(self.tables, out):
+            want = f64.ground_truth_kinds(c, a, lo[sub], hi[sub])
+            bad = (n[sub] != want["count"]) | (
+                np.abs(s[sub] - want["sum"])
+                > self.SELF_CHECK_RTOL * np.abs(want["sum"]))
+            if np.any(bad):
+                q = sub[np.flatnonzero(bad)[0]]
+                raise IndexMismatch(
+                    f"reference index disagrees with the plain scan on "
+                    f"{int(bad.sum())} of {sub.size} predicates; first: "
+                    f"lo={lo[q].tolist()} hi={hi[q].tolist()} index "
+                    f"(sum {s[q]!r}, count {n[q]!r}) scan (sum "
+                    f"{want['sum'][bad][0]!r}, count "
+                    f"{want['count'][bad][0]!r})")
+        self.self_checked += sub.size
 
     def at(self, parts, j: int, sl=slice(None)) -> dict:
         s, n, ps, pn = parts
         s, n = s[sl].copy(), n[sl].copy()
-        P = len(self.batches)
+        P = len(self.tables) - 1
         if P and j:
             times = np.full(P, j // P)
             times[: j % P] += 1
@@ -139,50 +183,57 @@ def compare(run) -> tuple[dict, list[str]]:
     limits = cfg["limits"]
     kinds = run.kinds
     tol = _rounding(run.sizes.k)
-    truth = Truth(run.c, run.a, run.stream)
+    t0 = time.perf_counter()
+    truth = Truth(run.c, run.a, run.stream, seed=run.seed)
+    t_index = time.perf_counter() - t0
     answered = [r for r in run.requests if r.result is not None]
+    probes = [r for r in run.probes if r.result is not None]
     lines = [f"check: compared {len(answered)} window requests "
              f"({sum(r.qidx.size for r in answered)} queries) and "
              f"{len(run.probes)} probe requests"]
 
     unanswered = sum(1 for r in run.requests + run.probes
                      if not r.shed and (r.t_done is None or r.error))
+    # one pass of the reference over every query compared, window first
+    bounds = [run.query_bounds(r) for r in answered + probes]
+    slices, off = [], 0
+    for b in bounds:
+        slices.append(slice(off, off + b[0].shape[0]))
+        off += b[0].shape[0]
+    if bounds:
+        parts = truth.parts(np.concatenate([b[0] for b in bounds]),
+                            np.concatenate([b[1] for b in bounds]))
+    lines.append(f"check: reference answered {off} queries in "
+                 f"{time.perf_counter() - t0!r} s (index {t_index!r} s); "
+                 f"{truth.self_checked} re-answered by the plain scan")
     bmiss = scored = missed = 0
     outside = {"earlier": 0, "later": 0}
-    if answered:
-        bounds = [run.query_bounds(r) for r in answered]
-        lo = np.concatenate([b[0] for b in bounds])
-        hi = np.concatenate([b[1] for b in bounds])
-        parts = truth.parts(lo, hi)
-        off = 0
-        for r in answered:
-            sl = slice(off, off + r.qidx.size)
-            off += r.qidx.size
+    for r, sl in zip(answered, slices):
 
-            def fit(j, r=r, sl=sl):
-                t = truth.at(parts, j, sl)
-                return (bound_misses(r.result, t, kinds, tol),
-                        *interval_scores(r.result, t, kinds, tol)[::-1])
+        def fit(j, r=r, sl=sl):
+            t = truth.at(parts, j, sl)
+            return (bound_misses(r.result, t, kinds, tol),
+                    *interval_scores(r.result, t, kinds, tol)[::-1])
 
-            if run.stream is None:
-                best = fit(0)
-            else:
-                # the served state is one of those the guarantee allows;
-                # score the answer against the one it fits best
-                j0, j1 = r.acked_at_submit, r.dispatched_at_done
-                best = min((fit(j) for j in range(j0, j1 + 1)),
-                           key=lambda f: (f[0], f[1]))
-                # the state one batch before and one after the allowed
-                # ones: an answer served from a state that misses an
-                # acknowledged batch fits the one before better, while
-                # the one after, which no answer can have been served
-                # from, counts how often that happens by chance
-                for j, name in ((j0 - 1, "earlier"), (j1 + 1, "later")):
-                    if j >= 0 and fit(j)[:2] < best[:2]:
-                        outside[name] += 1
-            bmiss += best[0]
-            missed += best[1]
-            scored += best[2]
+        if run.stream is None:
+            best = fit(0)
+        else:
+            # the served state is one of those the guarantee allows;
+            # score the answer against the one it fits best
+            j0, j1 = r.acked_at_submit, r.dispatched_at_done
+            best = min((fit(j) for j in range(j0, j1 + 1)),
+                       key=lambda f: (f[0], f[1]))
+            # the state one batch before and one after the allowed
+            # ones: an answer served from a state that misses an
+            # acknowledged batch fits the one before better, while
+            # the one after, which no answer can have been served
+            # from, counts how often that happens by chance
+            for j, name in ((j0 - 1, "earlier"), (j1 + 1, "later")):
+                if j >= 0 and fit(j)[:2] < best[:2]:
+                    outside[name] += 1
+        bmiss += best[0]
+        missed += best[1]
+        scored += best[2]
     ci_miss = missed / scored if scored else 0.0
     if run.stream is not None:
         lines.append(f"check: answers fitting a state outside the allowed "
@@ -204,14 +255,11 @@ def compare(run) -> tuple[dict, list[str]]:
                      f"served from a state outside the allowed ones")
 
     cov_err = 0.0
-    probes = [r for r in run.probes if r.result is not None]
-    if probes:
-        j = run.stream.dispatched if run.stream is not None else 0
-        for r in probes:
-            lo, hi = run.query_bounds(r)
-            t = truth.at(truth.parts(lo, hi), j)
-            cov_err = max(cov_err, covered_error_ulp(r.result, t, kinds))
-            bmiss += bound_misses(r.result, t, kinds, tol)
+    j = run.stream.dispatched if run.stream is not None else 0
+    for r, sl in zip(probes, slices[len(answered):]):
+        t = truth.at(parts, j, sl)
+        cov_err = max(cov_err, covered_error_ulp(r.result, t, kinds))
+        bmiss += bound_misses(r.result, t, kinds, tol)
     lines.append(f"check: {scored} non-point intervals scored, "
                  f"{missed} missed; {sum(r.qidx.size for r in probes)} "
                  f"covered probe queries")

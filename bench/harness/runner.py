@@ -166,7 +166,8 @@ class Run:
         syn_cfg = self.config["synopsis"]
         self.syn, _ = build_synopsis(
             self.c, self.a, k=self.sizes.k, sample_budget=self.sizes.samples,
-            method=syn_cfg["method"], seed=self.seeds["build"])
+            method=syn_cfg["method"], seed=self.seeds["build"],
+            allocation=syn_cfg.get("allocation", "equal"))
         if self.stream is not None:
             from repro.streaming import StreamingIngestor
             self.ingestor = StreamingIngestor(self.syn,

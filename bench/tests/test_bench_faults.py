@@ -4,7 +4,11 @@ control (one bf16 pass where the program asks for HIGHEST) and for every
 fault the cells can have. A sound run at the same size comes out true.
 
 Besides the committed cell, the cells left out of ``BENCHMARK.json`` for
-now (``taxi1d.dash``, ``taxi1d.ingest``) run from their committed files."""
+now (``taxi1d.dash``, ``taxi1d.ingest``) run from their committed files,
+and ``taxi3d.closed`` from a configuration that lives only in the test's
+root: the paper's multi-dimensional taxi deployment as the repository's
+Figure 8 reproduction sets it (KD-PASS, 64 leaves, 2% proportional
+samples, widths of 30-80% of the rows per column, d = 3)."""
 import contextlib
 import json
 import os
@@ -21,10 +25,23 @@ TINY = runner.Sizes(rows=40_000, k=64, samples=8192, query_pool=2048)
 LEFT_OUT = {"taxi1d.dash": "dash", "taxi1d.ingest": "ingest"}
 
 
+def nyc_taxi_3d() -> dict:
+    with open(os.path.join(_paths.BENCH, "configs", "nyc_taxi_1d.json")) as f:
+        config = json.load(f)
+    config["name"] = "nyc_taxi_3d"
+    config["data"] = dict(config["data"], dims=3, predicate_columns=[
+        "pickup_time_min", "dropoff_time_min", "pickup_location_id"])
+    config["synopsis"] = {"method": "kd", "k": 64, "sample_rate": 0.02,
+                          "allocation": "proportional"}
+    config["query_widths"] = {"min_frac": 0.3, "max_frac": 0.8}
+    return config
+
+
 @pytest.fixture(scope="module")
 def spec_root(tmp_path_factory):
     """A root whose BENCHMARK.json holds the committed cells and the ones
-    left out, all resolving to the committed files under ``bench/``."""
+    left out, resolving to the committed files under ``bench/``, and
+    ``taxi3d.closed`` with its configuration in the root."""
     with open(os.path.join(_paths.ROOT, "BENCHMARK.json")) as f:
         spec = json.load(f)
     for c in spec["configs"]:
@@ -33,6 +50,12 @@ def spec_root(tmp_path_factory):
                            "traffic": t, "chips": 1}
                           for n, t in LEFT_OUT.items()]
     root = tmp_path_factory.mktemp("spec")
+    (root / "nyc_taxi_3d.json").write_text(json.dumps(nyc_taxi_3d()))
+    spec["configs"].append({"name": "nyc_taxi_3d",
+                            "file": str(root / "nyc_taxi_3d.json")})
+    spec["workloads"].append({"name": "taxi3d.closed",
+                              "config": "nyc_taxi_3d", "traffic": "closed",
+                              "chips": 1})
     (root / "BENCHMARK.json").write_text(json.dumps(spec))
     return str(root)
 
@@ -56,7 +79,8 @@ def _run(spec_root, cell_name, seed=20260, seconds=1.0, backend=None):
                 os.environ["REPRO_KERNEL_BACKEND"] = old
 
 
-@pytest.mark.parametrize("cell_name", ["taxi1d.dash", "taxi1d.closed"])
+@pytest.mark.parametrize("cell_name", ["taxi1d.dash", "taxi1d.closed",
+                                       "taxi3d.closed"])
 def test_sound_run_is_correct(spec_root, cell_name):
     out = _run(spec_root, cell_name)
     assert out["correct"], out["checks"]
@@ -105,6 +129,8 @@ def test_serialized_ingest_run_is_correct(spec_root):
     ("taxi1d.closed", "answer_altered", "covered_err_ulp"),
     ("taxi1d.closed", "ci_halved", "ci_miss"),
     ("taxi1d.closed", "half_batch", "bound_miss"),
+    ("taxi3d.closed", "answer_altered", "covered_err_ulp"),
+    ("taxi3d.closed", "half_batch", "bound_miss"),
     ("taxi1d.ingest", "state_unchanged", "bound_miss"),
     ("taxi1d.ingest", "state_unchanged", "stale_reads"),
     ("taxi1d.ingest", "stale_merge", "stale_reads"),
